@@ -25,6 +25,8 @@ def main() -> int:
                          "(table1|fig2|fig4|kernels|roofline|stream|"
                          "stream_adapt)")
     args = ap.parse_args()
+    from repro.utils import init_compile_cache
+    init_compile_cache()
 
     from benchmarks import (fig2_bandwidth_energy, fig4_leakage, kernel_bench,
                             roofline_report, stream_adapt, stream_serving,

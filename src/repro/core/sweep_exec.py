@@ -16,8 +16,7 @@ way, so one executor abstraction serves both:
 first ``devices`` local devices, ``shard_map`` wrapping with pytree-prefix
 in/out specs, and leading-axis padding up to a device multiple (padded
 lanes compute real-but-discarded work; callers read back only the first
-``n`` lanes, so sharded and single-device runs stay bit-for-bit
-identical).
+``n`` lanes, so no element's result reads another element's values).
 
 On CPU CI the mesh comes from forced host devices::
 
@@ -37,7 +36,6 @@ from typing import Any, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec
 
 CFG_AXIS = "cfg"
@@ -111,8 +109,8 @@ class MeshExecutor:
         """
         if not self.is_sharded:
             return fn
-        return shard_map(fn, mesh=self.mesh, in_specs=tuple(in_specs),
-                         out_specs=out_specs, check_rep=False)
+        return jax.shard_map(fn, mesh=self.mesh, in_specs=tuple(in_specs),
+                             out_specs=out_specs, check_vma=False)
 
 
 @dataclass(frozen=True)

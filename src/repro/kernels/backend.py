@@ -2,13 +2,15 @@
 
 Every kernel wrapper takes ``interpret: bool | None = None``:
 
-  * ``None``  — autodetect: compile on a real TPU backend, fall back to
-    Pallas interpret mode everywhere else (CPU CI, GPU containers).
+  * ``None``  — autodetect: compile on the TPU backend, run Pallas
+    interpret mode on the CPU backend (CI, rehearsals). Any other backend
+    raises: a kernel never falls back to the interpreter on an
+    accelerator, where it would run orders of magnitude slower unnoticed.
     This is what lets the SAME call sites run compiled on hardware
     without plumbing a flag through every layer.
-  * ``True``/``False`` — explicit override (tests pin ``True``; a TPU
-    soak run may pin ``False`` to fail loudly if Mosaic rejects the
-    kernel instead of silently interpreting).
+  * ``True``/``False`` — explicit override. ``True`` is refused on any
+    backend but the CPU for the same reason; ``False`` forces a Mosaic
+    lowering (the described-topology compile tests use it on CPU).
 
 Compiled TPU kernels also need hardware-aligned tiles: the last (lane)
 axis must be a multiple of 128 and the second-to-last (sublane) axis a
@@ -25,9 +27,23 @@ SUBLANE = 8     # TPU sublane width (second-to-last axis), f32
 
 
 def resolve_interpret(interpret: bool | None) -> bool:
-    """``None`` → interpret everywhere except a real TPU backend."""
+    """``None`` → compile on TPU, interpret on CPU; raise elsewhere.
+
+    Interpret mode is only ever granted on the CPU backend, explicitly
+    requested or not."""
+    backend = jax.default_backend()
     if interpret is None:
-        return jax.default_backend() != "tpu"
+        if backend == "tpu":
+            return False
+        if backend == "cpu":
+            return True
+        raise RuntimeError(
+            f"Pallas kernels compile on 'tpu' and interpret on 'cpu'; the "
+            f"{backend!r} backend is neither")
+    if interpret and backend != "cpu":
+        raise RuntimeError(
+            f"interpret=True refused on the {backend!r} backend: a kernel "
+            f"on an accelerator must run compiled")
     return interpret
 
 

@@ -24,14 +24,14 @@ def _lif_kernel(x_ref, out_ref, *, tau: float, v_th: float, soft_reset: bool):
     T = x_ref.shape[0]
 
     def step(t, v):
-        x_t = pl.load(x_ref, (pl.ds(t, 1), slice(None)))[0]
+        x_t = x_ref[pl.ds(t, 1), :][0]
         v = v + (x_t - v) / tau
         s = (v > v_th).astype(x_ref.dtype)
         if soft_reset:
             v = v - s * v_th
         else:
             v = v * (1.0 - s)
-        pl.store(out_ref, (pl.ds(t, 1), slice(None)), s[None])
+        out_ref[pl.ds(t, 1), :] = s[None]
         return v
 
     v0 = jnp.zeros((x_ref.shape[1],), x_ref.dtype)

@@ -15,10 +15,12 @@ Layout: im2col patches [T_out, n_sub, P, K] (P = B·H'·W' sites, K = receptive
 field), weights [K, F]. The grid carries a **circuit-config axis** in front:
 grid = (n_cfg, T_out, P tiles), with the per-config leak linearization
 ``(v_inf, decay)`` AND the per-config comparator threshold ``theta`` (the
-variant grid's v_threshold axis) stored as [n_cfg, F] tensors indexed by
-the config grid dimension. Patches and weights are config-independent, so
-the same event tile is revisited once per config with only new [1, F]
-leak/threshold tiles loaded —
+variant grid's v_threshold axis) passed as [n_cfg, 1, F] tensors indexed
+by the config grid dimension (the singleton axis keeps each [1, F] tile
+full-extent in its last two dims, which the TPU tiling rule requires).
+Patches and weights are config-independent, so the same event tile is
+revisited once per config with only new [1, F] leak/threshold tiles
+loaded —
 this is what lets the co-design sweep engine (core/sweep.py) evaluate all
 three MAC circuit configs (and nullifier-mismatch variants) in ONE
 pallas_call instead of one compile per circuit. The n_sub loop runs inside
@@ -43,9 +45,9 @@ def _p2m_kernel(patches_ref, w_ref, vinf_ref, decay_ref, theta_ref,
     n_sub = patches_ref.shape[1]
     bp = patches_ref.shape[2]
     F = w_ref.shape[1]
-    vinf = vinf_ref[0, :]                      # [F] — this grid step's config
-    decay = decay_ref[0, :]
-    theta = theta_ref[0, :]                    # per-config comparator level
+    vinf = vinf_ref[0, 0, :]                   # [F] — this grid step's config
+    decay = decay_ref[0, 0, :]
+    theta = theta_ref[0, 0, :]                 # per-config comparator level
     pvg = pvg_ref[0, :]
     pvo = pvo_ref[0, :]
 
@@ -126,9 +128,11 @@ def p2m_conv_multi_pallas(patches: jax.Array, w: jax.Array, v_inf: jax.Array,
             pl.BlockSpec((1, n_sub, block_p, Kp),
                          lambda c, t, p: (t, 0, p, 0)),
             pl.BlockSpec((Kp, Fp), lambda c, t, p: (0, 0)),
-            pl.BlockSpec((1, Fp), lambda c, t, p: (c, 0)),
-            pl.BlockSpec((1, Fp), lambda c, t, p: (c, 0)),
-            pl.BlockSpec((1, Fp), lambda c, t, p: (c, 0)),
+            # per-config rows ride as [n_cfg, 1, Fp]: the tile's last two
+            # dims (1, Fp) are full-extent, as the (8, 128) rule demands
+            pl.BlockSpec((1, 1, Fp), lambda c, t, p: (c, 0, 0)),
+            pl.BlockSpec((1, 1, Fp), lambda c, t, p: (c, 0, 0)),
+            pl.BlockSpec((1, 1, Fp), lambda c, t, p: (c, 0, 0)),
             pl.BlockSpec((1, Fp), lambda c, t, p: (0, 0)),
             pl.BlockSpec((1, Fp), lambda c, t, p: (0, 0)),
         ],
@@ -141,7 +145,8 @@ def p2m_conv_multi_pallas(patches: jax.Array, w: jax.Array, v_inf: jax.Array,
             jax.ShapeDtypeStruct((n_cfg, T, P, Fp), jnp.float32),
         ],
         interpret=interpret,
-    )(patches, w, v_inf, decay, theta, pv_gain[None, :], pv_offset[None, :])
+    )(patches, w, v_inf[:, None, :], decay[:, None, :], theta[:, None, :],
+      pv_gain[None, :], pv_offset[None, :])
     return spikes[..., :F], vpre[..., :F]
 
 

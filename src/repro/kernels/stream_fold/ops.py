@@ -8,8 +8,11 @@ charge through one replay chunk's S fine sub-slots in ONE kernel launch.
 ``mode="deposit"`` (default) computes the per-sub-slot conv deposits
 with the SAME ``repro.core.p2m_layer._conv`` the XLA fold runs — one
 conv per sub-slot, identical shapes — then fuses the fold in-kernel.
-That makes the result bit-exact with the scan on every backend, which is
-the contract serving relies on. ``mode="mac"`` pushes the conv itself
+The result is bit-exact with the scan over those deposits (``use_ref``)
+and within a few ulp of the conv-fused XLA scan (the compiler's
+multiply-add contraction is the only difference); layer-1 spike maps
+and predictions are identical, which is the contract serving relies
+on. ``mode="mac"`` pushes the conv itself
 into the kernel as an im2col matmul (full fusion, no deposit tensor in
 HBM) at the cost of matmul-vs-conv summation-order drift (≤1e-5).
 """
@@ -20,7 +23,7 @@ import jax.numpy as jnp
 from jax import lax
 
 # the SAME conv the XLA fold and the offline curvefit forward run —
-# bit-exactness of mode="deposit" depends on it being imported, not copied
+# mode="deposit" parity depends on it being imported, not copied
 from repro.core.p2m_layer import _conv
 from repro.kernels.p2m_conv.ops import _extract_patches
 from repro.kernels.stream_fold.ref import stream_fold_mac_ref, stream_fold_ref

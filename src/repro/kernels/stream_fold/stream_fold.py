@@ -19,16 +19,18 @@ lane width in compiled mode):
 
 * :func:`stream_fold_pallas` — the serving default. Consumes
   PRE-COMPUTED per-sub-slot deposits ``c_k`` [S, N, F] and fuses the
-  fold. Because the deposit stream is produced by the very same conv
-  the XLA fold runs, the result is **bit-exact** with the ``lax.scan``
-  reference on every backend — the property the streaming parity suite
-  (tests/test_streaming.py) pins.
+  fold. It is **bit-exact** with ``ref.stream_fold_ref`` (the
+  ``lax.scan`` over the same deposits). The deposit stream comes from
+  the very conv the XLA serving fold runs, so the two folds differ only
+  where a compiler contracts a multiply and an add into one rounding
+  (fused multiply-add): a few ulp of the charge, with layer-1 spike maps
+  and predictions unchanged (tests/test_stream_fold.py,
+  tests/test_streaming.py).
 * :func:`stream_fold_mac_pallas` — full fusion: the conv itself runs
   in-kernel as an im2col matmul on the MXU (``patches[s] @ w``), so the
   [S, N, F] deposit tensor is never materialized in HBM. Float-exact
   up to matmul summation order vs the conv path (parity-tested to
-  1e-5), which is why serving keeps the deposit variant as the
-  bit-exactness oracle's twin.
+  1e-5), which is why serving keeps the deposit variant.
 
 HBM traffic per chunk drops from the scan's ~3·S·N·F (read x, read c,
 write x per step) to (S+1)·N·F reads + N·F writes (deposit variant) or
@@ -85,7 +87,7 @@ def stream_fold_pallas(x0: jax.Array, deposits: jax.Array, a: jax.Array, *,
 
     x0 [N, F] f32 charge carry; deposits [S, N, F]; a [F] per-filter
     decay. Returns the folded state [N, F], bit-exact with
-    ``ref.stream_fold_ref`` (the ``lax.scan`` fold).
+    ``ref.stream_fold_ref`` (the ``lax.scan`` fold of the same deposits).
     """
     S, N, F = deposits.shape
     assert x0.shape == (N, F), (x0.shape, (N, F))

@@ -17,7 +17,6 @@ from pathlib import Path
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.distributed.compression import compressed_allreduce
@@ -48,13 +47,13 @@ def main() -> int:
 
     t0 = time.perf_counter()
     with mesh:
-        fc = jax.jit(shard_map(reduce_compressed, mesh=mesh,
-                               in_specs=(P("pod"), P("pod")),
-                               out_specs=(P("pod"), P("pod")),
-                               check_rep=False))
+        fc = jax.jit(jax.shard_map(reduce_compressed, mesh=mesh,
+                                   in_specs=(P("pod"), P("pod")),
+                                   out_specs=(P("pod"), P("pod")),
+                                   check_vma=False))
         cc = fc.lower(g_sds, g_sds).compile()
-        ff = jax.jit(shard_map(reduce_fp32, mesh=mesh,
-                               in_specs=P("pod"), out_specs=P("pod")))
+        ff = jax.jit(jax.shard_map(reduce_fp32, mesh=mesh,
+                                   in_specs=P("pod"), out_specs=P("pod")))
         cf = ff.lower(g_sds).compile()
     comp = analyze_hlo(cc.as_text(), pod_stride=256)
     base = analyze_hlo(cf.as_text(), pod_stride=256)

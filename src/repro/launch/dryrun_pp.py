@@ -20,6 +20,7 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs import get_config
+from repro.launch.mesh import make_mesh
 from repro.roofline.hlo import analyze_hlo
 from repro.roofline.model import roofline_terms
 
@@ -41,8 +42,8 @@ def main() -> int:
     cfg = get_config(args.arch)
     assert cfg.family == "dense", "PP dry-run covers the dense family"
     assert cfg.n_layers % args.pipe == 0
-    mesh = jax.make_mesh((args.pipe, args.data, args.model),
-                         ("pipe", "data", "model"))
+    mesh = make_mesh((args.pipe, args.data, args.model),
+                     ("pipe", "data", "model"))
     t0 = time.perf_counter()
     with mesh:
         shapes = jax.eval_shape(

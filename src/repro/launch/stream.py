@@ -42,7 +42,7 @@ admission (shed/rejected/deferred) counters and — under ``--paced`` —
 deadline-miss accounting (docs/streaming.md).
 
 ``--devices N`` shards the lane axis over a 1-D device mesh
-(repro.stream.shard) — bit-identical to ``--devices 1``; ``--bin-workers``
+(repro.stream.shard) — the predictions of ``--devices 1``; ``--bin-workers``
 sizes the host binning pool (defaults to the device count). On CPU boxes,
 force host devices first: ``XLA_FLAGS=--xla_force_host_platform_device_count=8``.
 
@@ -133,7 +133,7 @@ def main() -> int:
     ap.add_argument("--devices", type=int, default=None,
                     help="shard the lane axis over this many devices on a "
                          "1-D mesh (capacity is padded up to a multiple; "
-                         "bit-identical to --devices 1). Default: "
+                         "the predictions of --devices 1). Default: "
                          "unsharded")
     ap.add_argument("--bin-workers", type=int, default=None,
                     help="host binning worker threads, each owning a "
@@ -178,8 +178,8 @@ def main() -> int:
     ap.add_argument("--use-kernel", action="store_true",
                     help="fold sub-slots through the fused Pallas "
                          "stream_fold kernel instead of the XLA scan "
-                         "(bit-exact; compiled on TPU, interpreted "
-                         "elsewhere — see docs/kernels.md)")
+                         "(same spike maps; compiled on TPU, "
+                         "interpreted on CPU — see docs/kernels.md)")
     ap.add_argument("--protocol", type=str, default="frozen",
                     choices=["frozen", "unfrozen"],
                     help="which phase-2 protocol to train+deploy when no "
@@ -195,6 +195,8 @@ def main() -> int:
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--out", type=str, default="artifacts/stream")
     args = ap.parse_args()
+    from repro.utils import init_compile_cache
+    init_compile_cache()
 
     from repro.data import sources as sources_mod
     from repro.stream import deploy as deploy_mod

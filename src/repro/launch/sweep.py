@@ -289,6 +289,8 @@ def main() -> int:
     ap.add_argument("--timeout", type=int, default=2400)
     ap.add_argument("--out", type=str, default=None)
     args = ap.parse_args()
+    from repro.utils import init_compile_cache
+    init_compile_cache()
 
     if args.dryrun_cells:
         args.pods = args.pods or [1, 2]

@@ -34,9 +34,9 @@ sharded :class:`~repro.stream.shard.LaneExecutor` and the fold/readout
 bodies run under ``shard_map`` over a 1-D ``"lane"`` mesh — one
 contiguous lane block per device, the deployed weights replicated. Every
 lane's numerics are independent of its neighbours (no cross-lane
-reduction anywhere in the serving forward), which is what makes sharded
-and single-device serving bit-for-bit identical
-(tests/test_stream_shard.py).
+reduction anywhere in the serving forward), which is what gives sharded
+serving the predictions and spike counts of single-device serving, with
+logits to a few ulp (tests/test_stream_shard.py).
 """
 from __future__ import annotations
 
@@ -195,7 +195,7 @@ def make_stream_fns(dep: Deployment, *, capacity: int,
     boundaries. ``use_kernel=True`` routes the sub-slot fold through the
     fused Pallas stream_fold kernel (one launch per chunk, charge tile
     VMEM-resident — see docs/kernels.md); the XLA ``lax.scan`` fold
-    below is its bit-exactness oracle and stays the default.
+    below is its parity reference and stays the default.
 
     A sharded ``executor`` (repro.stream.shard.LaneExecutor) partitions
     the lane axis over the 1-D ``"lane"`` mesh: ``capacity`` must then be

@@ -82,8 +82,8 @@ a lane freed on any shard. Host binning scales with it: ``bin_workers``
 their lanes one chunk ahead of the device — the multi-worker attack on
 the host-bound saturation knee. Sharded serving, any worker count, and
 ``prefetch=False`` (the bit-identical inline oracle) all produce
-bit-for-bit identical predictions and ledgers to the ``devices=1``
-single-worker path.
+identical predictions and ledgers to the ``devices=1`` single-worker
+path (sharded logits to a few ulp).
 """
 from __future__ import annotations
 
@@ -143,6 +143,9 @@ class StreamResult:
     # single-deployment engine); uid disambiguates across hot-swaps
     entry: str = "default"
     entry_uid: int = 0
+    # pooled layer-1 spikes the stream's sensor emitted over all readouts
+    # (its share of total_layer1_spikes: the in-pixel layer's bandwidth)
+    n_layer1_spikes: float = 0.0
     logits: list[float] = field(default_factory=list)  # rate-decoded mean
 
 
@@ -157,6 +160,7 @@ class _Lane:
     admitted_window: int = 0
     windows_done: int = 0
     n_events: int = 0
+    n_layer1_spikes: float = 0.0
     t_cursor_us: int = 0
     n_misses: int = 0
     worst_margin_ms: float | None = None
@@ -423,16 +427,18 @@ class StreamEngine:
     (must divide ``n_sub``; default: one chunk per fine sub-slot, the
     finest arrival granularity the binned contract expresses).
     ``use_kernel=True`` folds each chunk's sub-slots through the fused
-    Pallas stream_fold kernel instead of the XLA scan (bit-exact either
-    way — tests/test_stream_fold.py pins it). ``prefetch=False`` turns
+    Pallas stream_fold kernel instead of the XLA scan (identical spike
+    maps and predictions, charge to a few ulp — tests/test_stream_fold.py
+    pins it). ``prefetch=False`` turns
     off the async host-binning workers and bins chunks inline on the
     serving thread (debug aid; the folded numbers are identical).
 
     ``executor`` (repro.stream.shard.LaneExecutor) shards the lane axis
     over a 1-D ``"lane"`` device mesh: the capacity pads up to a multiple
     of ``executor.devices`` (padding lanes are never admitted) and the
-    jitted steps run under ``shard_map`` — bit-for-bit identical to the
-    default single-device executor. ``bin_workers`` sets the host binning
+    jitted steps run under ``shard_map`` — identical predictions, ledgers
+    and spike counts to the default single-device executor, logits to a
+    few ulp. ``bin_workers`` sets the host binning
     pool width (default: one worker per mesh shard, so ``devices=1``
     keeps the single-worker pipeline); each worker owns a fixed disjoint
     slice of the lane axis, which keeps per-lane chunk order — and the
@@ -933,6 +939,7 @@ class StreamEngine:
                     report.total_readouts += 1
                     row = row_of(lane)
                     row["n_readouts"] += 1
+                    lane.n_layer1_spikes += float(n_spikes[lane_i])
                     report.total_layer1_spikes += float(n_spikes[lane_i])
                     if margin_ms is not None:
                         report.miss_margin_ms.append(margin_ms)
@@ -965,6 +972,7 @@ class StreamEngine:
                         n_misses=lane.n_misses,
                         miss_margin_max_ms=lane.worst_margin_ms,
                         entry=lane.entry_name, entry_uid=lane.entry_uid,
+                        n_layer1_spikes=lane.n_layer1_spikes,
                         logits=[float(v) for v in logits]))
                     slots.release(lane_i)
                     if self.adapt is not None:

@@ -14,8 +14,9 @@ pads the lane capacity to a multiple of n and runs each device's
 ``capacity / n`` lanes under ``shard_map``. Padded lanes are never
 admitted (their ``active`` mask stays False, and the per-shard
 :class:`~repro.serve.slots.ShardedSlots` bookkeeping never places a
-stream on them), so sharded serving is bit-for-bit identical to
-``devices=1`` — the same parity bar the sweep executor set
+stream on them), so sharded serving gives the predictions, ledgers and
+spike counts of ``devices=1`` exactly and its logits to a few ulp — a
+device's smaller lane batch may get another reduction order
 (tests/test_stream_shard.py pins it).
 
 On CPU CI the mesh comes from forced host devices, mirroring the sweep::

@@ -34,10 +34,10 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.configs.base import LMConfig
+from repro.launch.mesh import make_mesh
 from repro.models import lm
 from repro.nn import layers as L
 
@@ -46,7 +46,7 @@ Params = dict
 
 def make_pp_mesh(pipe: int = 4, data: int = 8, model: int = 8) -> Mesh:
     """(pipe, data, model) mesh — pipe stages map to ICI-neighbour groups."""
-    return jax.make_mesh((pipe, data, model), ("pipe", "data", "model"))
+    return make_mesh((pipe, data, model), ("pipe", "data", "model"))
 
 
 def stage_params(key: jax.Array, cfg: LMConfig, n_stages: int) -> Params:
@@ -157,12 +157,12 @@ def pipeline_apply(params: Params, tokens: jax.Array, labels: jax.Array,
     lab_mb = labels.reshape(M, B // M, labels.shape[1])
 
     embed_specs = jax.tree.map(lambda _: P(), params["embed"])
-    fn = shard_map(
+    fn = jax.shard_map(
         staged, mesh=mesh,
         in_specs=(P("pipe"), embed_specs, P(),
                   P(None, "data", None), P(None, "data", None)),
         out_specs=P("pipe"),
-        check_rep=False)
+        check_vma=False)
     losses = fn(params["blocks"], params["embed"], params["final_norm"],
                 tok_mb, lab_mb)
     return jnp.mean(losses)

@@ -2,6 +2,8 @@
 from __future__ import annotations
 
 import math
+import os
+from pathlib import Path
 from typing import Any, Callable, Iterator
 
 import jax
@@ -9,6 +11,25 @@ import jax.numpy as jnp
 import numpy as np
 
 PyTree = Any
+
+# the persistent compile cache lives at one fixed path in the checkout
+# (src/repro/utils.py -> repo root): the path is part of the cache key,
+# and a directory that moves never hits
+COMPILE_CACHE_DIR = Path(__file__).resolve().parents[2] / ".jax_cache"
+
+
+def init_compile_cache() -> str:
+    """Point JAX's persistent compilation cache at a stable directory.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and
+    this sets nothing; otherwise the cache goes to :data:`COMPILE_CACHE_DIR`
+    inside the checkout. Call it before the first compile. Returns the
+    directory in effect."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    jax.config.update("jax_compilation_cache_dir", str(COMPILE_CACHE_DIR))
+    return str(COMPILE_CACHE_DIR)
 
 
 def tree_size(tree: PyTree) -> int:

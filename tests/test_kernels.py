@@ -5,6 +5,27 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+
+@pytest.mark.parametrize("backend,interpret,want", [
+    ("tpu", None, False), ("cpu", None, True), ("gpu", None, RuntimeError),
+    ("tpu", True, RuntimeError), ("gpu", True, RuntimeError),
+    ("cpu", True, True), ("tpu", False, False), ("cpu", False, False),
+])
+def test_resolve_interpret_only_on_cpu(monkeypatch, backend, interpret,
+                                       want):
+    """Interpret mode is granted on the CPU backend only: autodetect
+    compiles on TPU and refuses any other backend, and an explicit
+    ``interpret=True`` off the CPU is refused too."""
+    from repro.kernels import backend as kb
+
+    monkeypatch.setattr(kb.jax, "default_backend", lambda: backend)
+    if want is RuntimeError:
+        with pytest.raises(RuntimeError):
+            kb.resolve_interpret(interpret)
+    else:
+        assert kb.resolve_interpret(interpret) is want
+
+
 # ---------------------------------------------------------------------------
 # p2m_conv
 # ---------------------------------------------------------------------------

@@ -197,7 +197,6 @@ class TestCompression:
         from repro.distributed import (CompressionState,
                                        init_error_feedback)
         from repro.distributed.compression import tree_compressed_allreduce
-        import jax.experimental.shard_map as shard_map
         from jax.sharding import Mesh, PartitionSpec as P
 
         mesh = Mesh(np.array(jax.devices()[:1]), ("data",))
@@ -209,9 +208,9 @@ class TestCompression:
                 g, CompressionState(residual=res), "data")
             return out, new_state.residual
 
-        fm = shard_map.shard_map(
+        fm = jax.shard_map(
             f, mesh=mesh, in_specs=(P(), P()), out_specs=(P(), P()),
-            check_rep=False)   # all_gather-based reduce defeats rep inference
+            check_vma=False)   # all_gather-based reduce defeats rep inference
         out, res = fm(grads, state.residual)
         np.testing.assert_allclose(np.asarray(out["w"]),
                                    np.asarray(grads["w"]), atol=0.05)

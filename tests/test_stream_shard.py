@@ -4,11 +4,13 @@ repro.serve.slots.ShardedSlots): sharded-vs-single-device serving parity
 plus in-process unit coverage of the lane executor and the per-shard slot
 bookkeeping.
 
-The parity bar is EXACT equality — every lane's serving forward is
-independent of its neighbours (no cross-lane reduction), so shard_map
-partitioning must not change a single bit of any prediction, logit
-vector, admission ledger entry, or spike count, for any device count,
-padded or not, paced or unpaced, prefetching or inline.
+The parity bar: no lane's numerics read a neighbour's (no cross-lane
+reduction), so every prediction, admission ledger entry and layer-1 spike
+count is EXACTLY equal to the unsharded serve, for any device count,
+padded or not, paced or unpaced, prefetching or inline. Logits agree to
+``MESH_ATOL``: the per-device batch is smaller than the whole lane axis,
+and the compiler may pick another reduction order for a backbone matmul
+at that shape (on the CPU, one lane per device moves a logit by one ulp).
 """
 import os
 import subprocess
@@ -27,6 +29,8 @@ from repro.stream.shard import (LANE_AXIS, LaneExecutor,  # noqa: E402
                                 make_lane_executor)
 
 REPO = Path(__file__).resolve().parents[1]
+# sharded vs one-device logits: a few ulp of |logit| <~ 1
+MESH_ATOL = 1e-6
 
 
 class TestLaneExecutor:
@@ -172,8 +176,9 @@ class TestShardedServing:
             assert a.prediction == b.prediction
             assert a.n_events == b.n_events
             assert a.admitted_window == b.admitted_window
-            np.testing.assert_array_equal(np.asarray(a.logits),
-                                          np.asarray(b.logits))
+            np.testing.assert_allclose(np.asarray(a.logits),
+                                       np.asarray(b.logits), rtol=0,
+                                       atol=MESH_ATOL)
         assert got.total_layer1_spikes == base.total_layer1_spikes
         art = got.to_artifact()
         assert art["sharding"]["devices"] == n_dev
@@ -226,12 +231,13 @@ _PARITY_SCRIPT = textwrap.dedent("""
             assert a.offered_window == b.offered_window, tag
             assert a.admitted_window == b.admitted_window, tag
             assert a.finished_window == b.finished_window, tag
-            np.testing.assert_array_equal(np.asarray(a.logits),
-                                          np.asarray(b.logits))
+            np.testing.assert_allclose(np.asarray(a.logits),
+                                       np.asarray(b.logits), rtol=0,
+                                       atol={atol!r}, err_msg=tag)
         for k in ("n_offered", "n_admitted", "n_shed", "n_deferred",
                   "total_events", "total_readouts", "total_layer1_spikes"):
             assert getattr(a_rep, k) == getattr(b_rep, k), (tag, k)
-        print(tag, "bitexact")
+        print(tag, "parity")
 
     # capacity 4: divisible (2, 4) and padded (8 -> padded_capacity 8
     # with 4 padding lanes); capacity 3 over 2 devices pads 3 -> 4
@@ -265,12 +271,13 @@ _PARITY_SCRIPT = textwrap.dedent("""
 def test_sharded_serving_matches_single_device():
     """Forced 8-host-device run: devices in {2, 4, 8} plus a
     non-divisible capacity (3 lanes over 2 devices), paced, inline
-    (prefetch=False), and multi-worker binning — every prediction, logit
-    vector, ledger counter, and spike count exactly equal to the
-    unsharded serve."""
+    (prefetch=False), and multi-worker binning — every prediction, ledger
+    counter, and spike count exactly equal to the unsharded serve, logits
+    within ``MESH_ATOL``."""
     env = dict(os.environ, PYTHONPATH=str(REPO / "src"))
     env.pop("XLA_FLAGS", None)   # the script must own the device count
-    proc = subprocess.run([sys.executable, "-c", _PARITY_SCRIPT], env=env,
+    script = _PARITY_SCRIPT.replace("{atol!r}", repr(MESH_ATOL))
+    proc = subprocess.run([sys.executable, "-c", script], env=env,
                           capture_output=True, text=True, timeout=1200)
     assert proc.returncode == 0, proc.stderr[-4000:]
     assert "PARITY_PASS" in proc.stdout

@@ -224,8 +224,9 @@ class TestStreamingOfflineParity:
                                          tmp_path):
         """The fused stream_fold kernel path (use_kernel=True) holds the
         SAME offline-parity contract across the full 2 circuits ×
-        2 T_INTG grid — the kernel is bit-exact with the scan fold, so
-        the telescoping to the offline curve-fit forward survives."""
+        2 T_INTG grid — the kernel folds the scan's deposits to a few
+        ulp, so the telescoping to the offline curve-fit forward
+        survives."""
         records = trained["results"]["frozen"].records
         for record in records:
             self._parity_case(trained, file_source, tmp_path / "kern",
@@ -437,6 +438,9 @@ class TestEngineLifecycle:
         # continuous batching: later streams admitted at later windows
         assert max(r.admitted_window for r in report.results) > 0
         assert report.total_readouts == 5 * n_windows
+        # per-stream layer-1 spike counts partition the fleet total
+        assert sum(r.n_layer1_spikes for r in report.results) == \
+            report.total_layer1_spikes > 0
 
     def test_stats_artifact_schema(self):
         src = sources.resolve_dataset("synthetic-gesture", hw=HW)

@@ -17,7 +17,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.experimental import enable_x64
+from jax import enable_x64
 
 from repro.core import codesign, snn
 from repro.core import sweep as engine
@@ -61,7 +61,7 @@ class TestFiniteDifference:
     @pytest.mark.parametrize("circuit", CIRCUITS, ids=lambda c: c.value)
     def test_grad_matches_fd_per_circuit(self, circuit):
         with enable_x64():
-            cfg, params, ev = _setup(AnalogConfig(weight_levels=1 << 22))
+            cfg, params, ev = _setup(AnalogConfig(weight_levels=1 << 40))
             leak_cfgs = (LeakageConfig(circuit=circuit),)
             kc, kd = jax.random.split(jax.random.PRNGKey(42))
 
