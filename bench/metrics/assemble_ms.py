@@ -1,0 +1,14 @@
+"""Host feed: ms per chunk to assemble the workers' per-lane frames into
+the fold's dense lane batch (``_assemble``).
+
+Mean duration of the serving loop's ``p2m.assemble`` spans (``bench/spans.py``)
+that start inside the traced bracket. The profiler slows the host there
+by about a quarter, so this splits the traced window among the loop's
+steps; it does not restate the untraced ``host_feed_ms`` and
+``window_sync_ms``, read outside the bracket. Moves ``events_per_s``.
+"""
+from bench import spans
+
+
+def reduce(ctx):
+    return spans.span_ms(ctx, "p2m.assemble")

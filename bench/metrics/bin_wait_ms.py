@@ -1,0 +1,14 @@
+"""Host feed: ms per chunk the serving thread waits for the binning
+workers' frames (binning inline when the engine does not prefetch).
+
+Mean duration of the serving loop's ``p2m.bin_wait`` spans (``bench/spans.py``)
+that start inside the traced bracket. The profiler slows the host there
+by about a quarter, so this splits the traced window among the loop's
+steps; it does not restate the untraced ``host_feed_ms`` and
+``window_sync_ms``, read outside the bracket. Moves ``events_per_s``.
+"""
+from bench import spans
+
+
+def reduce(ctx):
+    return spans.span_ms(ctx, "p2m.bin_wait")

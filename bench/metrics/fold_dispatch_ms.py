@@ -1,0 +1,15 @@
+"""Host feed (dispatch): ms per chunk to dispatch the jitted fold, which
+returns before the device runs it; on a TPU it also carries the dense
+frames' host relayout for the transfer.
+
+Mean duration of the serving loop's ``p2m.fold`` spans (``bench/spans.py``)
+that start inside the traced bracket. The profiler slows the host there
+by about a quarter, so this splits the traced window among the loop's
+steps; it does not restate the untraced ``host_feed_ms`` and
+``window_sync_ms``, read outside the bracket. Moves ``events_per_s``.
+"""
+from bench import spans
+
+
+def reduce(ctx):
+    return spans.span_ms(ctx, "p2m.fold")

@@ -1,0 +1,15 @@
+"""Readout sync: ms per window the serving thread waits on the readout's
+spike counts, the window's one device-to-host read; it holds every fold
+still queued on the device.
+
+Mean duration of the serving loop's ``p2m.sync`` spans (``bench/spans.py``)
+that start inside the traced bracket. The profiler slows the host there
+by about a quarter, so this splits the traced window among the loop's
+steps; it does not restate the untraced ``host_feed_ms`` and
+``window_sync_ms``, read outside the bracket. Moves ``events_per_s``.
+"""
+from bench import spans
+
+
+def reduce(ctx):
+    return spans.span_ms(ctx, "p2m.sync")

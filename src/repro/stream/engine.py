@@ -21,10 +21,13 @@ Lifecycle of one stream (see docs/streaming.md):
      resident iterators never exceed the lane capacity;
   3. every replay tick, each occupied lane's next chunk is binned onto
      the fine sub-slot grid (repro.data.binning semantics, sensor →
-     model downscale included) by a host-side worker thread that runs
-     one chunk ahead of the device, and ONE jitted lane-batched ``fold``
-     advances every lane's leak ODE + conv deposit together — no
-     per-tick host sync; the window's only sync point is its readout;
+     model downscale included) by a host-side worker thread — a
+     window's binning jobs are all submitted at the window's start, so
+     the worker bins the later chunks while the serving thread feeds
+     the earlier ones, and the first chunk of every window waits for
+     its own binning — and ONE jitted lane-batched ``fold`` advances
+     every lane's leak ODE + conv deposit together — no per-tick host
+     sync; the window's only sync point is its readout;
   4. at each T_INTG boundary one jitted ``readout`` comparator-reads
      every lane, accumulates pooled spikes toward the backbone coarse
      grid, and — per lane, whenever ITS coarse window completes — steps
@@ -79,11 +82,17 @@ behind the SAME single admission front — one bounded pending deque feeds
 a lane freed on any shard. Host binning scales with it: ``bin_workers``
 :class:`_BinWorker` threads each own a disjoint slice of the lane axis
 (aligned with the mesh shards when ``bin_workers == devices``) and bin
-their lanes one chunk ahead of the device — the multi-worker attack on
-the host-bound saturation knee. Sharded serving, any worker count, and
-``prefetch=False`` (the bit-identical inline oracle) all produce
-identical predictions and ledgers to the ``devices=1`` single-worker
-path (sharded logits to a few ulp).
+their lanes in parallel, from the jobs submitted at each window's start
+— the multi-worker attack on the host-bound saturation knee. Sharded
+serving, any worker count, and ``prefetch=False`` (the bit-identical
+inline oracle) all produce identical predictions and ledgers to the
+``devices=1`` single-worker path (sharded logits to a few ulp).
+
+**Profiling.** Each step of the serving loop opens a
+``jax.profiler.TraceAnnotation`` named ``p2m.<step>``, tagged with the
+window, chunk, stream and lane it serves and the counts of its work; a
+profiler trace puts them on the device's clock (docs/streaming.md,
+"Profiling").
 """
 from __future__ import annotations
 
@@ -98,6 +107,7 @@ from typing import Iterator
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.data.binning import bin_chunks, slot_us_for
 from repro.data.formats import EventChunk
@@ -170,9 +180,11 @@ class _Lane:
 
 
 class _BinWorker:
-    """Single host-side worker thread binning replay chunks ahead of the
-    device fold (async host binning: while the device folds chunk ``c``,
-    the worker bins chunk ``c+1``). Jobs are executed strictly in
+    """Single host-side worker thread binning replay chunks off the
+    serving thread (async host binning: a window's jobs are submitted at
+    its start, so while the serving thread assembles and dispatches
+    chunk ``c`` the worker bins chunk ``c+1``; the window's first chunk
+    waits for its own binning). Jobs are executed strictly in
     submission order — a lane's replay iterator is only ever advanced on
     the ONE worker that owns that lane, so chunk order per lane is
     preserved. Exceptions propagate to the consumer at ``get()``."""
@@ -631,17 +643,21 @@ class StreamEngine:
         return _Lane(stream_id=stream_id, label=label, chunks=chunks,
                      n_windows=n_windows)
 
-    def _bin_chunk(self, source: EventSource, lane: _Lane) -> np.ndarray:
-        """Next replay chunk of ``lane`` → fine sub-slot frames
-        [chunk_slots, H, W, 2] (offline-binner semantics: same slot grid,
-        same sensor → model downscale)."""
-        chunk = next(lane.chunks)
-        lane.n_events += len(chunk)
-        frames = bin_chunks([chunk], n_total=self.chunk_slots,
-                            slot_us=self.slot_us,
-                            sensor_hw=source.sensor_hw,
-                            out_hw=self.fns.in_hw,
-                            t0_us=lane.t_cursor_us)
+    def _bin_chunk(self, source: EventSource, lane: _Lane,
+                   lane_i: int) -> np.ndarray:
+        """Next replay chunk of ``lane`` (global lane ``lane_i``) → fine
+        sub-slot frames [chunk_slots, H, W, 2] (offline-binner semantics:
+        same slot grid, same sensor → model downscale)."""
+        with TraceAnnotation("p2m.bin", stream=lane.stream_id,
+                             lane=lane_i) as span:
+            chunk = next(lane.chunks)
+            span.set_metadata(events=len(chunk))
+            lane.n_events += len(chunk)
+            frames = bin_chunks([chunk], n_total=self.chunk_slots,
+                                slot_us=self.slot_us,
+                                sensor_hw=source.sensor_hw,
+                                out_hw=self.fns.in_hw,
+                                t0_us=lane.t_cursor_us)
         lane.t_cursor_us += self.chunk_us
         return frames
 
@@ -668,7 +684,7 @@ class StreamEngine:
         """One worker's share of a replay tick: each owned occupied
         lane's next chunk, binned to [chunk_slots, H, W, 2]. Runs on the
         owning :class:`_BinWorker` thread when prefetching."""
-        return [(lane_i, self._bin_chunk(source, lane))
+        return [(lane_i, self._bin_chunk(source, lane, lane_i))
                 for lane_i, lane in lanes]
 
     def _assemble(self, parts: list[list[tuple[int, np.ndarray]]]
@@ -798,117 +814,147 @@ class StreamEngine:
                    or not slots.is_empty()):
                 # ---- ops hook (hot-swap point): runs before this
                 # window's admissions so a swap at window k governs
-                # every stream admitted at k onward ---------------------
+                # every stream admitted at k onward; outside every span,
+                # since a harness may open its own there ----------------
                 if on_window is not None:
                     on_window(window)
-                # ---- offers arriving at this window boundary ----------
-                while (next_offer < n_streams
-                       and offer_window(next_offer) <= window):
-                    report.n_offered += 1
-                    if (max_pending is not None
-                            and len(pending) >= max_pending + slots.n_free):
-                        report.n_shed += 1
-                        if log is not None:
-                            log(f"[admission] shed stream {next_offer} at "
-                                f"window {window} (pending full)")
-                    else:
-                        pending.append((next_offer, window))
-                    next_offer += 1
-                # ---- lazy admission into free lanes (window boundary) -
-                while pending and not slots.is_full():
-                    sid, offered_w = pending.popleft()
-                    if self.registry is not None:
-                        # variant selection: resolve the stream's request
-                        # against the LIVE registry; unresolvable →
-                        # reject (never guess a variant for a sensor)
-                        try:
-                            entry = self.registry.resolve(
-                                req_of(sid), compat=self.compat,
-                                default=self.default_entry)
-                            slot_e = self._bind_entry(entry)
-                        except (LookupError, ValueError, TypeError,
-                                EntryTableFull) as e:
-                            report.n_rejected += 1
+                with TraceAnnotation("p2m.schedule", window=window) as span:
+                    n_admitted, n_shed = report.n_admitted, report.n_shed
+                    # ---- offers arriving at this window boundary ------
+                    while (next_offer < n_streams
+                           and offer_window(next_offer) <= window):
+                        report.n_offered += 1
+                        if (max_pending is not None
+                                and len(pending) >= max_pending + slots.n_free):
+                            report.n_shed += 1
                             if log is not None:
-                                log(f"[admission] rejected stream {sid} "
-                                    f"at window {window}: {e}")
-                            continue
-                    lane = self.open_stream(
-                        source, jax.random.fold_in(key, sid), sid)
-                    lane.offered_window = offered_w
-                    lane.admitted_window = window
-                    if window > offered_w:
-                        report.n_deferred += 1
-                    lane_i = slots.admit(lane)
-                    assert lane_i is not None
-                    if self.registry is not None:
-                        lane.entry_name = entry.name
-                        lane.entry_uid = entry.uid
-                        lane.entry_slot = slot_e
-                        self._entry_of[lane_i] = slot_e
-                    state = self.fns.reset_lane(state, lane_i)
-                    if self.adapt is not None:
-                        # learned deltas persist across streams on the
-                        # lane (it models one physical sensor) but are
-                        # void against a different base entry
-                        uid = entry.uid if self.registry is not None else 0
-                        if self._lane_entry_uid[lane_i] == uid:
-                            self.adapt_state = \
-                                self.fns.reset_lane_transient(
-                                    self.adapt_state, lane_i)
+                                log(f"[admission] shed stream {next_offer} at "
+                                    f"window {window} (pending full)")
                         else:
-                            self.adapt_state = self.fns.reset_lane_full(
-                                self.adapt_state, lane_i)
-                        self._lane_entry_uid[lane_i] = uid
-                        self._lane_base[lane_i] = (
-                            entry.dep if self.registry is not None
-                            else self.dep)
-                        self._lane_base_name[lane_i] = lane.entry_name
-                        self._labels[lane_i] = lane.label
-                    report.n_admitted += 1
-                    row_of(lane)["n_admitted"] += 1
-                    report.per_shard_admitted[slots.shard_of(lane_i)] += 1
-                report.max_open_streams = max(report.max_open_streams,
-                                              slots.n_occupied)
-                occupied = list(slots.occupied())
-                active = jnp.asarray(slots.active_mask())
-                # registry mode: this window's per-lane entry indices +
-                # the (possibly just re-stacked) param bundle ride along
-                # as jitted-step arguments — same shapes, no recompile
-                extra = (() if self.registry is None else
-                         (jnp.asarray(self._entry_of), self._bundle))
+                            pending.append((next_offer, window))
+                        next_offer += 1
+                    # ---- lazy admission into free lanes (window boundary)
+                    while pending and not slots.is_full():
+                        sid, offered_w = pending.popleft()
+                        with TraceAnnotation("p2m.admit", window=window,
+                                             stream=sid) as admit:
+                            if self.registry is not None:
+                                # variant selection: resolve the stream's
+                                # request against the LIVE registry;
+                                # unresolvable → reject (never guess a
+                                # variant for a sensor)
+                                try:
+                                    entry = self.registry.resolve(
+                                        req_of(sid), compat=self.compat,
+                                        default=self.default_entry)
+                                    slot_e = self._bind_entry(entry)
+                                except (LookupError, ValueError, TypeError,
+                                        EntryTableFull) as e:
+                                    report.n_rejected += 1
+                                    admit.set_metadata(lane=-1)
+                                    if log is not None:
+                                        log(f"[admission] rejected stream "
+                                            f"{sid} at window {window}: {e}")
+                                    continue
+                            lane = self.open_stream(
+                                source, jax.random.fold_in(key, sid), sid)
+                            lane.offered_window = offered_w
+                            lane.admitted_window = window
+                            if window > offered_w:
+                                report.n_deferred += 1
+                            lane_i = slots.admit(lane)
+                            assert lane_i is not None
+                            admit.set_metadata(lane=lane_i)
+                            if self.registry is not None:
+                                lane.entry_name = entry.name
+                                lane.entry_uid = entry.uid
+                                lane.entry_slot = slot_e
+                                self._entry_of[lane_i] = slot_e
+                            state = self.fns.reset_lane(state, lane_i)
+                            if self.adapt is not None:
+                                # learned deltas persist across streams on
+                                # the lane (it models one physical sensor)
+                                # but are void against a different base
+                                # entry
+                                uid = (entry.uid if self.registry is not None
+                                       else 0)
+                                if self._lane_entry_uid[lane_i] == uid:
+                                    self.adapt_state = \
+                                        self.fns.reset_lane_transient(
+                                            self.adapt_state, lane_i)
+                                else:
+                                    self.adapt_state = \
+                                        self.fns.reset_lane_full(
+                                            self.adapt_state, lane_i)
+                                self._lane_entry_uid[lane_i] = uid
+                                self._lane_base[lane_i] = (
+                                    entry.dep if self.registry is not None
+                                    else self.dep)
+                                self._lane_base_name[lane_i] = lane.entry_name
+                                self._labels[lane_i] = lane.label
+                            report.n_admitted += 1
+                            row_of(lane)["n_admitted"] += 1
+                            report.per_shard_admitted[
+                                slots.shard_of(lane_i)] += 1
+                    report.max_open_streams = max(report.max_open_streams,
+                                                  slots.n_occupied)
+                    occupied = list(slots.occupied())
+                    active = jnp.asarray(slots.active_mask())
+                    # registry mode: this window's per-lane entry indices
+                    # + the (possibly just re-stacked) param bundle ride
+                    # along as jitted-step arguments — same shapes, no
+                    # recompile
+                    extra = (() if self.registry is None else
+                             (jnp.asarray(self._entry_of), self._bundle))
+                    span.set_metadata(n_admitted=report.n_admitted - n_admitted,
+                                      n_shed=report.n_shed - n_shed)
                 # ---- paced: hold until this window's wall-clock start -
                 if paced:
                     delay = (t_start + window * t_intg_s
                              - time.perf_counter())
-                    if delay > 0:
-                        time.sleep(delay)
+                    with TraceAnnotation("p2m.pace", window=window,
+                                         late_ms=max(0.0, -delay) * 1e3):
+                        if delay > 0:
+                            time.sleep(delay)
                 # ---- fold the window's replay chunks ------------------
-                # binning runs one chunk ahead on the worker pool (each
-                # worker bins only its own lane slice, in parallel) and
-                # the fold dispatches are left in flight — the window's
-                # only host↔device sync is the readout below
+                # the window's binning jobs are all submitted now, so the
+                # workers (each on its own lane slice, in parallel) bin
+                # the later chunks while this thread feeds the earlier
+                # ones; the fold dispatches are left in flight — the
+                # window's only host↔device sync is the readout below
                 parts_by_worker = self._partition(occupied)
                 if pool is not None:
                     for _ in range(self.chunks_per_window):
                         for wi, lanes in enumerate(parts_by_worker):
                             pool.submit(wi, lambda ls=lanes:
                                         self._bin_part(source, ls))
-                for _ in range(self.chunks_per_window):
+                for chunk in range(self.chunks_per_window):
                     t0 = time.perf_counter()
-                    parts = ([pool.get(wi)
-                              for wi in range(self.bin_workers)]
-                             if pool is not None else
-                             [self._bin_part(source, ls)
-                              for ls in parts_by_worker])
-                    frames = self._assemble(parts)
-                    if self.adapt is None:
-                        state = self.fns.fold(state, jnp.asarray(frames),
-                                              active, *extra)
-                    else:
-                        state, self.adapt_state = self.fns.fold(
-                            state, self.adapt_state, jnp.asarray(frames),
-                            active, *extra)
+                    with TraceAnnotation("p2m.bin_wait", window=window,
+                                         chunk=chunk):
+                        parts = ([pool.get(wi)
+                                  for wi in range(self.bin_workers)]
+                                 if pool is not None else
+                                 [self._bin_part(source, ls)
+                                  for ls in parts_by_worker])
+                    with TraceAnnotation("p2m.assemble", window=window,
+                                         chunk=chunk, lanes=len(occupied)):
+                        frames = self._assemble(parts)
+                    # a name of its own leaves the host frames alive
+                    # until the next chunk's assembly, as with the copy
+                    # inline in the fold call
+                    with TraceAnnotation("p2m.h2d", window=window,
+                                         chunk=chunk, bytes=frames.nbytes):
+                        frames_dev = jnp.asarray(frames)
+                    with TraceAnnotation("p2m.fold", window=window,
+                                         chunk=chunk):
+                        if self.adapt is None:
+                            state = self.fns.fold(state, frames_dev, active,
+                                                  *extra)
+                        else:
+                            state, self.adapt_state = self.fns.fold(
+                                state, self.adapt_state, frames_dev, active,
+                                *extra)
                     report.fold_s.append(time.perf_counter() - t0)
                 # ---- readout at the T_INTG boundary -------------------
                 coarse_mask = np.zeros((self.padded_capacity,), bool)
@@ -916,16 +962,17 @@ class StreamEngine:
                     coarse_mask[lane_i] = \
                         (lane.windows_done + 1) % self.group == 0
                 t0 = time.perf_counter()
-                if self.adapt is None:
-                    state, out = self.fns.readout(state, active,
-                                                  jnp.asarray(coarse_mask),
-                                                  *extra)
-                else:
-                    state, self.adapt_state, out = self.fns.readout(
-                        state, self.adapt_state, active,
-                        jnp.asarray(coarse_mask),
-                        jnp.asarray(self._labels), *extra)
-                n_spikes = np.asarray(out["n_spikes"])  # window sync point
+                with TraceAnnotation("p2m.readout", window=window):
+                    if self.adapt is None:
+                        state, out = self.fns.readout(
+                            state, active, jnp.asarray(coarse_mask), *extra)
+                    else:
+                        state, self.adapt_state, out = self.fns.readout(
+                            state, self.adapt_state, active,
+                            jnp.asarray(coarse_mask),
+                            jnp.asarray(self._labels), *extra)
+                with TraceAnnotation("p2m.sync", window=window):
+                    n_spikes = np.asarray(out["n_spikes"])  # window sync
                 t_done = time.perf_counter()
                 report.readout_s.append(t_done - t0)
                 # paced: every occupied lane's readout k carries deadline
@@ -953,32 +1000,36 @@ class StreamEngine:
                     if lane.windows_done < lane.n_windows:
                         continue
                     # stream complete: finalize rate-decoded prediction
-                    n_c = int(state["n_coarse"][lane_i])
-                    logits = (np.asarray(state["logits"][lane_i])
-                              / max(n_c, 1))
-                    pred = int(np.argmax(logits))
-                    report.total_events += lane.n_events
-                    row["n_finished"] += 1
-                    row["n_correct"] += int(pred == lane.label)
-                    row["n_events"] += lane.n_events
-                    results.append(StreamResult(
-                        stream_id=lane.stream_id, label=lane.label,
-                        prediction=pred, correct=pred == lane.label,
-                        n_events=lane.n_events,
-                        n_readouts=lane.windows_done, n_coarse_frames=n_c,
-                        offered_window=lane.offered_window,
-                        admitted_window=lane.admitted_window,
-                        finished_window=window,
-                        n_misses=lane.n_misses,
-                        miss_margin_max_ms=lane.worst_margin_ms,
-                        entry=lane.entry_name, entry_uid=lane.entry_uid,
-                        n_layer1_spikes=lane.n_layer1_spikes,
-                        logits=[float(v) for v in logits]))
-                    slots.release(lane_i)
-                    if self.adapt is not None:
-                        self._labels[lane_i] = -1
-                    if self.registry is not None:
-                        self._unbind_entry(lane.entry_slot)
+                    # (tagged with the window whose readout finished it)
+                    with TraceAnnotation("p2m.finalise", window=window - 1,
+                                         stream=lane.stream_id, lane=lane_i):
+                        n_c = int(state["n_coarse"][lane_i])
+                        logits = (np.asarray(state["logits"][lane_i])
+                                  / max(n_c, 1))
+                        pred = int(np.argmax(logits))
+                        report.total_events += lane.n_events
+                        row["n_finished"] += 1
+                        row["n_correct"] += int(pred == lane.label)
+                        row["n_events"] += lane.n_events
+                        results.append(StreamResult(
+                            stream_id=lane.stream_id, label=lane.label,
+                            prediction=pred, correct=pred == lane.label,
+                            n_events=lane.n_events,
+                            n_readouts=lane.windows_done,
+                            n_coarse_frames=n_c,
+                            offered_window=lane.offered_window,
+                            admitted_window=lane.admitted_window,
+                            finished_window=window,
+                            n_misses=lane.n_misses,
+                            miss_margin_max_ms=lane.worst_margin_ms,
+                            entry=lane.entry_name, entry_uid=lane.entry_uid,
+                            n_layer1_spikes=lane.n_layer1_spikes,
+                            logits=[float(v) for v in logits]))
+                        slots.release(lane_i)
+                        if self.adapt is not None:
+                            self._labels[lane_i] = -1
+                        if self.registry is not None:
+                            self._unbind_entry(lane.entry_slot)
                     if log is not None:
                         log(f"[stream {lane.stream_id}] label={lane.label} "
                             f"pred={pred} readouts={lane.windows_done} "
