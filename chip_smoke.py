@@ -77,8 +77,9 @@ def phase_deploy(model_cfg, *, seed: int, directory: Path):
 
 
 def _warm_up(engine):
-    """Compile the engine's fold/readout on a zero state (what ``serve``
-    does before its clock starts); returns (seconds, folded state)."""
+    """Compile the engine's fold, on the window shape it dispatches, and
+    its readout on a zero state (what ``serve`` does before its clock
+    starts); returns (seconds, folded state)."""
     import jax
     import jax.numpy as jnp
 
@@ -87,7 +88,7 @@ def _warm_up(engine):
     cap = engine.padded_capacity
     mask = jnp.zeros((cap,), bool)
     state = engine.fns.fold(engine.fns.init_state(),
-                            jnp.zeros((cap, engine.chunk_slots, h, w, 2)),
+                            jnp.zeros((cap, engine.n_sub, h, w, 2)),
                             mask)
     state, _ = engine.fns.readout(state, mask, mask)
     jax.block_until_ready(state)
@@ -95,15 +96,16 @@ def _warm_up(engine):
 
 
 def fold_lowering_has_kernel(engine) -> bool:
-    """Whether the engine's jitted fold step lowers to a Mosaic kernel
-    (``tpu_custom_call``) rather than XLA ops or the interpreter."""
+    """Whether the engine's jitted fold step, on the window shape it
+    dispatches, lowers to a Mosaic kernel (``tpu_custom_call``) rather
+    than XLA ops or the interpreter."""
     import jax.numpy as jnp
 
     h, w = engine.fns.in_hw
     cap = engine.padded_capacity
     lowered = engine.fns.fold.lower(
         engine.fns.init_state(),
-        jnp.zeros((cap, engine.chunk_slots, h, w, 2)),
+        jnp.zeros((cap, engine.n_sub, h, w, 2)),
         jnp.zeros((cap,), bool))
     return "tpu_custom_call" in lowered.as_text()
 
@@ -235,7 +237,7 @@ def phase_serve(dep, source, *, n_streams: int, capacity: int, seed: int,
             f"events={report.total_events} "
             f"readouts={report.total_readouts} " + _compare_line(cmp))
         out[tag] = {"report": report, **cmp}
-    # one chunk through both folds from the same non-zero charge: how far
+    # one window through both folds from the same non-zero charge: how far
     # the kernel's arithmetic sits from the XLA fold's on this backend
     xla, kern = engines["xla"].fns, engines["kernel"].fns
     key = jax.random.PRNGKey(seed)
@@ -245,12 +247,12 @@ def phase_serve(dep, source, *, n_streams: int, capacity: int, seed: int,
     state["x"] = jax.random.normal(key, state["x"].shape) * 0.05
     frames = jax.random.poisson(
         jax.random.fold_in(key, 1), 0.3,
-        (cap, engines["xla"].chunk_slots, h, w, 2)).astype(jnp.float32)
+        (cap, engines["xla"].n_sub, h, w, 2)).astype(jnp.float32)
     active = jnp.ones((cap,), bool)
     xa = np.asarray(xla.fold(dict(state), frames, active)["x"])
     xk = np.asarray(kern.fold(dict(state), frames, active)["x"])
     out["fold_max_dx"] = float(np.abs(xa - xk).max())
-    log(f"[fold] kernel vs XLA fold, one chunk: max|dx|="
+    log(f"[fold] kernel vs XLA fold, one window: max|dx|="
         f"{out['fold_max_dx']:.3e} (|x| max {float(np.abs(xa).max()):.3e})")
     return out
 

@@ -152,8 +152,8 @@ def stack_entries(numerics: list[dict]) -> dict:
 
 def _fold_core(x: jax.Array, frames: jax.Array, nb: dict, *,
                stride: int, dv_unit: float, use_kernel: bool) -> jax.Array:
-    """One variant's chunk fold: advance the charge ODE of every lane
-    through ``frames`` [capacity, chunk_slots, H, W, 2] under numerics
+    """One variant's fold: advance the charge ODE of every lane
+    through ``frames`` [capacity, S, H, W, 2] under numerics
     ``nb`` (:func:`entry_numerics`). Each sub-slot decays the standing
     charge by ``a`` and deposits its (dv_unit-scaled) conv — empty slots
     decay without deposit."""
@@ -190,12 +190,14 @@ def make_stream_fns(dep: Deployment, *, capacity: int,
     """Build the jitted lane-batched fold/readout steps for ``dep``.
 
     ``chunk_slots`` is the number of fine sub-slots one replay chunk
-    spans (``fold`` consumes frames ``[capacity, chunk_slots, H, W, 2]``);
-    it must divide ``n_sub`` so T_INTG boundaries land on chunk
-    boundaries. ``use_kernel=True`` routes the sub-slot fold through the
-    fused Pallas stream_fold kernel (one launch per chunk, charge tile
-    VMEM-resident — see docs/kernels.md); the XLA ``lax.scan`` fold
-    below is its parity reference and stays the default.
+    spans; it must divide ``n_sub`` so T_INTG boundaries land on chunk
+    boundaries. ``fold`` consumes frames ``[capacity, S, H, W, 2]`` for
+    any sub-slot count ``S``: the engine folds a whole window at once
+    (``S = n_sub``). ``use_kernel=True`` routes the sub-slot fold
+    through the fused Pallas stream_fold kernel (one launch per fold
+    call, charge tile VMEM-resident — see docs/kernels.md); the XLA
+    ``lax.scan`` fold below is its parity reference and stays the
+    default.
 
     A sharded ``executor`` (repro.stream.shard.LaneExecutor) partitions
     the lane axis over the 1-D ``"lane"`` mesh: ``capacity`` must then be
@@ -252,10 +254,11 @@ def make_stream_fns(dep: Deployment, *, capacity: int,
 
     def fold_body(state: dict, frames: jax.Array, active: jax.Array
                   ) -> dict:
-        """Advance the charge ODE through one replay chunk.
+        """Advance the charge ODE through ``S`` sub-slots.
 
-        ``frames`` [capacity, chunk_slots, H, W, 2] — the chunk's events
-        binned on the fine sub-slot grid; ``active`` [capacity] bool.
+        ``frames`` [capacity, S, H, W, 2] — events binned on the fine
+        sub-slot grid (the engine passes one T_INTG window, S = n_sub);
+        ``active`` [capacity] bool.
         Each sub-slot decays the standing charge by ``a`` and deposits
         its (dv_unit-scaled) conv — empty slots decay without deposit.
         Under a sharded executor this body sees one device's contiguous

@@ -146,7 +146,7 @@ def make_adapt_fns(dep: Deployment, *, capacity: int, chunk_slots: int,
     (same masking, same state update) but is vmapped per lane so each
     lane serves under its OWN ``quantize(w_base + dw)`` /
     ``theta_base + dtheta`` numerics, re-linearized through
-    ``relinearized_numerics`` each chunk. ``registry=True`` builds the
+    ``relinearized_numerics`` each fold. ``registry=True`` builds the
     multi-variant flavor: fold/readout take ``(entry, bundle)`` and
     gather each lane's base numerics before applying its deltas.
     """

@@ -23,11 +23,14 @@ Lifecycle of one stream (see docs/streaming.md):
      the fine sub-slot grid (repro.data.binning semantics, sensor →
      model downscale included) by a host-side worker thread — a
      window's binning jobs are all submitted at the window's start, so
-     the worker bins the later chunks while the serving thread feeds
-     the earlier ones, and the first chunk of every window waits for
-     its own binning — and ONE jitted lane-batched ``fold`` advances
-     every lane's leak ODE + conv deposit together — no per-tick host
-     sync; the window's only sync point is its readout;
+     the worker bins the later chunks while the serving thread
+     assembles the earlier ones, and the first chunk of every window
+     waits for its own binning — into the window's sub-slot range of
+     one dense batch; once the window's last chunk is in, ONE host →
+     device copy and ONE jitted lane-batched ``fold`` advance every
+     lane's leak ODE + conv deposit through all of the window's
+     sub-slots together — no per-tick host sync; the window's only
+     sync point is its readout;
   4. at each T_INTG boundary one jitted ``readout`` comparator-reads
      every lane, accumulates pooled spikes toward the backbone coarse
      grid, and — per lane, whenever ITS coarse window completes — steps
@@ -182,9 +185,9 @@ class _Lane:
 class _BinWorker:
     """Single host-side worker thread binning replay chunks off the
     serving thread (async host binning: a window's jobs are submitted at
-    its start, so while the serving thread assembles and dispatches
-    chunk ``c`` the worker bins chunk ``c+1``; the window's first chunk
-    waits for its own binning). Jobs are executed strictly in
+    its start, so while the serving thread assembles chunk ``c`` the
+    worker bins chunk ``c+1``; the window's first chunk waits for its
+    own binning). Jobs are executed strictly in
     submission order — a lane's replay iterator is only ever advanced on
     the ONE worker that owns that lane, so chunk order per lane is
     preserved. Exceptions propagate to the consumer at ``get()``."""
@@ -434,11 +437,14 @@ class StreamEngine:
     single-variant serving (tests/test_registry.py).
 
     ``capacity`` is the fixed lane count of the jitted steps (the decode
-    batch of LM serving); ``chunks_per_window`` sets the replay
-    granularity — how many raw-event chunks arrive per T_INTG window
-    (must divide ``n_sub``; default: one chunk per fine sub-slot, the
-    finest arrival granularity the binned contract expresses).
-    ``use_kernel=True`` folds each chunk's sub-slots through the fused
+    batch of LM serving); ``chunks_per_window`` sets the replay and
+    binning granularity — how many raw-event chunks arrive, and are
+    binned, per T_INTG window (must divide ``n_sub``; default: one
+    chunk per fine sub-slot, the finest arrival granularity the binned
+    contract expresses). The device sees whole windows whatever it is:
+    the chunks are assembled into one ``[capacity, n_sub, H, W, 2]``
+    batch, copied once and folded by one call per window.
+    ``use_kernel=True`` folds the window's sub-slots through the fused
     Pallas stream_fold kernel instead of the XLA scan (identical spike
     maps and predictions, charge to a few ulp — tests/test_stream_fold.py
     pins it). ``prefetch=False`` turns
@@ -687,18 +693,22 @@ class StreamEngine:
         return [(lane_i, self._bin_chunk(source, lane, lane_i))
                 for lane_i, lane in lanes]
 
-    def _assemble(self, parts: list[list[tuple[int, np.ndarray]]]
-                  ) -> np.ndarray:
-        """Workers' per-lane blocks → the fold's full
-        [padded_capacity, chunk_slots, H, W, 2] batch (unoccupied and
-        mesh-padding lanes stay zero; they fold masked-inactive)."""
+    def _window_frames(self) -> np.ndarray:
+        """A zeroed host batch for one window's fold:
+        [padded_capacity, n_sub, H, W, 2] (unoccupied and mesh-padding
+        lanes stay zero; they fold masked-inactive)."""
         h, w = self.fns.in_hw
-        frames = np.zeros((self.padded_capacity, self.chunk_slots, h, w, 2),
-                          np.float32)
+        return np.zeros((self.padded_capacity, self.n_sub, h, w, 2),
+                        np.float32)
+
+    def _assemble(self, parts: list[list[tuple[int, np.ndarray]]],
+                  frames: np.ndarray, chunk: int) -> None:
+        """Write the workers' per-lane blocks of replay chunk ``chunk``
+        into its sub-slot range of the window batch ``frames``."""
+        lo = chunk * self.chunk_slots
         for part in parts:
             for lane_i, block in part:
-                frames[lane_i] = block
-        return frames
+                frames[lane_i, lo:lo + self.chunk_slots] = block
 
     # ------------------------------------------------------------------
     def serve(self, source: EventSource, n_streams: int, *, seed: int = 0,
@@ -787,14 +797,13 @@ class StreamEngine:
                 report.entry_rows.append(rows[k])
             return rows[k]
 
-        h, w = self.fns.in_hw
         # warmup: compile fold/readout on a throwaway state so the
-        # latency percentiles measure steady-state serving, not jit
+        # latency percentiles measure steady-state serving, not jit; the
+        # fold compiles at the one shape serving dispatches, a window
         wx = (() if self.registry is None else
               (jnp.zeros((self.padded_capacity,), jnp.int32), self._bundle))
         wmask = jnp.zeros((self.padded_capacity,), bool)
-        wframes = jnp.zeros((self.padded_capacity,
-                             self.chunk_slots, h, w, 2))
+        wframes = jnp.asarray(self._window_frames())
         if self.adapt is None:
             ws = self.fns.fold(self.fns.init_state(), wframes, wmask, *wx)
             ws, _ = self.fns.readout(ws, wmask, wmask, *wx)
@@ -919,15 +928,18 @@ class StreamEngine:
                 # ---- fold the window's replay chunks ------------------
                 # the window's binning jobs are all submitted now, so the
                 # workers (each on its own lane slice, in parallel) bin
-                # the later chunks while this thread feeds the earlier
-                # ones; the fold dispatches are left in flight — the
-                # window's only host↔device sync is the readout below
+                # the later chunks while this thread assembles the
+                # earlier ones into the window's batch; the batch then
+                # goes to the device in one copy and one fold dispatch,
+                # left in flight — the window's only host↔device sync is
+                # the readout below
                 parts_by_worker = self._partition(occupied)
                 if pool is not None:
                     for _ in range(self.chunks_per_window):
                         for wi, lanes in enumerate(parts_by_worker):
                             pool.submit(wi, lambda ls=lanes:
                                         self._bin_part(source, ls))
+                last = self.chunks_per_window - 1
                 for chunk in range(self.chunks_per_window):
                     t0 = time.perf_counter()
                     with TraceAnnotation("p2m.bin_wait", window=window,
@@ -939,22 +951,32 @@ class StreamEngine:
                                   for ls in parts_by_worker])
                     with TraceAnnotation("p2m.assemble", window=window,
                                          chunk=chunk, lanes=len(occupied)):
-                        frames = self._assemble(parts)
-                    # a name of its own leaves the host frames alive
-                    # until the next chunk's assembly, as with the copy
-                    # inline in the fold call
-                    with TraceAnnotation("p2m.h2d", window=window,
-                                         chunk=chunk, bytes=frames.nbytes):
-                        frames_dev = jnp.asarray(frames)
-                    with TraceAnnotation("p2m.fold", window=window,
-                                         chunk=chunk):
-                        if self.adapt is None:
-                            state = self.fns.fold(state, frames_dev, active,
-                                                  *extra)
-                        else:
-                            state, self.adapt_state = self.fns.fold(
-                                state, self.adapt_state, frames_dev, active,
-                                *extra)
+                        if chunk == 0:
+                            # a fresh batch every window, never reused:
+                            # the last window's transfer may still read
+                            # its own (on the CPU jnp.asarray may alias
+                            # it), freed only as this one replaces it
+                            frames = self._window_frames()
+                        self._assemble(parts, frames, chunk)
+                    if chunk == last:
+                        with TraceAnnotation(
+                                "p2m.h2d", window=window,
+                                chunks=self.chunks_per_window,
+                                slots=self.n_sub, bytes=frames.nbytes):
+                            frames_dev = jnp.asarray(frames)
+                        with TraceAnnotation(
+                                "p2m.fold", window=window,
+                                chunks=self.chunks_per_window,
+                                slots=self.n_sub):
+                            if self.adapt is None:
+                                state = self.fns.fold(state, frames_dev,
+                                                      active, *extra)
+                            else:
+                                state, self.adapt_state = self.fns.fold(
+                                    state, self.adapt_state, frames_dev,
+                                    active, *extra)
+                    # one entry per chunk: its bin wait and assembly, and
+                    # on the last the window's copy and fold dispatch
                     report.fold_s.append(time.perf_counter() - t0)
                 # ---- readout at the T_INTG boundary -------------------
                 coarse_mask = np.zeros((self.padded_capacity,), bool)

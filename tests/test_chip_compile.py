@@ -99,14 +99,18 @@ def test_lif(one_chip):
              _sds((8, LANES * 64 * 64 * F), one_chip))
 
 
-def test_serving_fold_step_with_kernel(one_chip, monkeypatch):
+@pytest.mark.parametrize("slots", [1, S], ids=["chunk", "window"])
+def test_serving_fold_step_with_kernel(one_chip, monkeypatch, slots):
     """The engine's jitted ``use_kernel=True`` fold at the paper config
-    holds the Mosaic kernel. This process's backend is the CPU, where the
-    kernel would interpret, so the test pins compiled mode."""
+    holds the Mosaic kernel, on one sub-slot and on the whole window of
+    ``n_sub`` sub-slots that serving dispatches. This process's backend
+    is the CPU, where the kernel would interpret, so the test pins
+    compiled mode."""
     from repro.configs import p2m_dvs
     from repro.kernels.stream_fold import stream_fold
     from repro.stream import accumulator, deploy
 
+    assert p2m_dvs.CONFIG.p2m.n_sub == S
     monkeypatch.setattr(stream_fold, "resolve_interpret", lambda _: False)
     dep = deploy.fresh_deployment(p2m_dvs.CONFIG, seed=0)
     capacity = 16
@@ -115,6 +119,6 @@ def test_serving_fold_step_with_kernel(one_chip, monkeypatch):
     state = jax.eval_shape(fns.init_state)
     state = jax.tree.map(lambda s: _sds(s.shape, one_chip, s.dtype), state)
     compiled = fns.fold.lower(
-        state, _sds((capacity, 1, HW, HW, 2), one_chip),
+        state, _sds((capacity, slots, HW, HW, 2), one_chip),
         _sds((capacity,), one_chip, jnp.bool_)).compile()
     assert "tpu_custom_call" in compiled.as_text()

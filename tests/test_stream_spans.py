@@ -105,7 +105,7 @@ def test_every_span_counts_its_work(run, request):
     chunks = windows * engine.chunks_per_window
     want = {"p2m.schedule": windows, "p2m.readout": windows,
             "p2m.sync": windows, "p2m.bin_wait": chunks,
-            "p2m.assemble": chunks, "p2m.h2d": chunks, "p2m.fold": chunks,
+            "p2m.assemble": chunks, "p2m.h2d": windows, "p2m.fold": windows,
             "p2m.admit": report.n_admitted,
             "p2m.finalise": len(report.results),
             "p2m.bin": report.total_readouts * engine.chunks_per_window,
@@ -115,6 +115,12 @@ def test_every_span_counts_its_work(run, request):
     # the engine's outer timers keep one entry per chunk / per window
     assert len(report.fold_s) == chunks
     assert len(report.readout_s) == windows
+    # one copy and one fold per window, each carrying all of its chunks
+    for name in ("p2m.h2d", "p2m.fold"):
+        stats = _stats(events, name)
+        assert sum(s["chunks"] for s in stats) == chunks
+        assert {(s["chunks"], s["slots"]) for s in stats} \
+            == {(engine.chunks_per_window, engine.n_sub)}
     # the counts sit on the spans where the work happens
     assert sum(s["events"] for s in _stats(events, "p2m.bin")) \
         == report.total_events
@@ -141,7 +147,7 @@ def test_admit_nests_in_schedule(prefetched):
 
 def test_h2d_bytes_are_the_dense_frames(prefetched):
     _, engine, _, events = prefetched
-    nbytes = (engine.padded_capacity * engine.chunk_slots * HW * HW * 2
+    nbytes = (engine.padded_capacity * engine.n_sub * HW * HW * 2
               * np.dtype(np.float32).itemsize)
     h2d = _stats(events, "p2m.h2d")
     assert h2d and all(s["bytes"] == nbytes for s in h2d)

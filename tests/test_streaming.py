@@ -539,7 +539,8 @@ def _fresh_dep(src):
     return deploy_mod.fresh_deployment(model, seed=0)
 
 
-def _fast_dep(src, t_intg_ms=100.0, coarse_ms=200.0):
+def _fast_dep(src, t_intg_ms=100.0, coarse_ms=200.0, *, n_sub=2,
+              circuit=CircuitConfig.NULLIFIED, seed=0):
     """Small deployment with a short T_INTG so paced runs finish fast."""
     from repro.core.codesign import P2MModelConfig
     from repro.core.leakage import LeakageConfig
@@ -547,13 +548,13 @@ def _fast_dep(src, t_intg_ms=100.0, coarse_ms=200.0):
     from repro.core.snn import SpikingCNNConfig
 
     model = P2MModelConfig(
-        p2m=P2MConfig(out_channels=8, n_sub=2, t_intg_ms=t_intg_ms,
-                      leak=LeakageConfig(circuit=CircuitConfig.NULLIFIED)),
+        p2m=P2MConfig(out_channels=8, n_sub=n_sub, t_intg_ms=t_intg_ms,
+                      leak=LeakageConfig(circuit=circuit)),
         backbone=SpikingCNNConfig(channels=(8, 16), input_hw=(HW, HW),
                                   fc_hidden=32, n_classes=src.n_classes,
                                   first_layer_external=True),
         coarse_window_ms=coarse_ms)
-    return deploy_mod.fresh_deployment(model, seed=0)
+    return deploy_mod.fresh_deployment(model, seed=seed)
 
 
 # ---------------------------------------------------------------------------
@@ -844,6 +845,130 @@ class TestBinningPool:
                                       duration_ms=400.0)
         with pytest.raises(ValueError, match="bin_workers"):
             StreamEngine(_fast_dep(src), capacity=2, bin_workers=0)
+
+
+# ---------------------------------------------------------------------------
+# one host-to-device copy and one fold per T_INTG window
+# ---------------------------------------------------------------------------
+
+@pytest.fixture(scope="module")
+def window_src():
+    return sources.resolve_dataset("synthetic-gesture", hw=HW,
+                                   duration_ms=400.0)
+
+
+@pytest.fixture(scope="module")
+def window_deps(window_src):
+    """Two compat-equal deployments of 4 sub-slots a window, so a window
+    can arrive in 1, 2 or 4 replay chunks."""
+    return (_fast_dep(window_src, n_sub=4),
+            _fast_dep(window_src, n_sub=4, circuit=CircuitConfig.BASIC,
+                      seed=1))
+
+
+def _window_engine(deps, mode: str, chunks_per_window: int):
+    """A 2-lane engine in ``mode`` and the ``variants`` its serve takes."""
+    from repro.stream.adapt import AdaptConfig
+    from repro.stream.registry import Registry
+
+    kw = {"capacity": 2, "chunks_per_window": chunks_per_window}
+    if mode == "registry":
+        reg = Registry()
+        reg.register("a", deps[0])
+        reg.register("b", deps[1])
+        return StreamEngine(reg, **kw), ["a", "b", "a"]
+    if mode == "adapt":
+        return StreamEngine(deps[0], adapt=AdaptConfig(), **kw), None
+    return StreamEngine(deps[0], prefetch=mode != "inline", **kw), None
+
+
+def _wrap_fold(engine, wrap) -> None:
+    """Replace the engine's jitted fold by ``wrap(fold, frames_at)``;
+    ``frames_at`` is the frames' position among the fold's arguments."""
+    import dataclasses
+
+    frames_at = 1 if engine.adapt is None else 2
+    engine.fns = dataclasses.replace(
+        engine.fns, fold=wrap(engine.fns.fold, frames_at))
+
+
+def _per_chunk(fold, frames_at: int, chunk_slots: int):
+    """The oracle: the same jitted fold driven chunk by chunk over the
+    window batch's sub-slot ranges, one call per replay chunk."""
+    def fold_chunks(*args):
+        carry, frames = args[:frames_at], args[frames_at]
+        rest = args[frames_at + 1:]
+        for lo in range(0, frames.shape[1], chunk_slots):
+            out = fold(*carry, frames[:, lo:lo + chunk_slots], *rest)
+            carry = out if frames_at == 2 else (out,)
+        return out
+    return fold_chunks
+
+
+@pytest.mark.parametrize("chunks_per_window", [1, 2, 4])
+@pytest.mark.parametrize("mode", ["plain", "registry", "adapt", "inline"])
+def test_one_fold_per_window_matches_per_chunk_folds(
+        window_src, window_deps, mode, chunks_per_window):
+    """``serve`` folds each T_INTG window in ONE call on the whole
+    [capacity, n_sub, H, W, 2] batch, whatever the replay granularity;
+    its answers are those of folding the same frames one replay chunk at
+    a time: predictions, spike counts and readout counts exactly, logits
+    to a few ulp. 3 streams on 2 lanes, so a lane turns over."""
+    engine, variants = _window_engine(window_deps, mode, chunks_per_window)
+    seen = []
+
+    def counted(fold, frames_at):
+        def call(*args):
+            seen.append(np.asarray(args[frames_at]))
+            return fold(*args)
+        return call
+
+    _wrap_fold(engine, counted)
+    got = engine.serve(window_src, 3, seed=0, variants=variants)
+
+    oracle, _ = _window_engine(window_deps, mode, chunks_per_window)
+    _wrap_fold(oracle, lambda fold, at: _per_chunk(fold, at,
+                                                   oracle.chunk_slots))
+    ref = oracle.serve(window_src, 3, seed=0, variants=variants)
+
+    windows = len(got.readout_s)
+    assert windows == max(r.finished_window for r in got.results) > 0
+    # the warm-up's call, then one per window, each on all n_sub slots
+    assert [f.shape[1] for f in seen] == [engine.n_sub] * (1 + windows)
+    assert len(got.fold_s) == windows * chunks_per_window
+    # streams 0 and 1 hold lanes 0 and 1 from the first window: each
+    # window's batch holds their next n_sub slots as the offline binner
+    # bins the whole stream
+    key0 = jax.random.PRNGKey(0)
+    n_windows = window_src.n_slots(engine.dep.t_intg_ms)
+    for lane in (0, 1):
+        _, chunks = window_src.iter_event_chunks(
+            jax.random.fold_in(key0, lane), chunk_us=engine.chunk_us,
+            slot_us=engine.slot_us)
+        offline = bin_chunks([concat_chunks(chunks)],
+                             n_total=n_windows * engine.n_sub,
+                             slot_us=engine.slot_us,
+                             sensor_hw=window_src.sensor_hw,
+                             out_hw=(HW, HW))
+        assert offline.any()
+        np.testing.assert_array_equal(
+            np.stack([f[lane] for f in seen[1:1 + n_windows]]),
+            offline.reshape(n_windows, engine.n_sub, HW, HW, 2))
+    assert len(got.results) == len(ref.results) == 3
+    key = lambda r: r.stream_id  # noqa: E731
+    for a, b in zip(sorted(ref.results, key=key),
+                    sorted(got.results, key=key)):
+        assert (a.stream_id, a.prediction, a.n_readouts, a.n_events,
+                a.n_layer1_spikes) == (b.stream_id, b.prediction,
+                                       b.n_readouts, b.n_events,
+                                       b.n_layer1_spikes)
+        np.testing.assert_array_max_ulp(
+            np.asarray(a.logits, np.float32),
+            np.asarray(b.logits, np.float32), maxulp=4)
+    for k in ("total_events", "total_readouts", "total_layer1_spikes"):
+        assert getattr(ref, k) == getattr(got, k), k
+    if mode == "adapt":
+        assert got.adaptation["n_updates"] == ref.adaptation["n_updates"] > 0
 
 
 # ---------------------------------------------------------------------------
