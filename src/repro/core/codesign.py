@@ -21,7 +21,8 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from repro.core import p2m_layer, snn
+from repro.core import backbone, p2m_layer, snn
+from repro.core.backbone import BackboneConfig
 from repro.core.leakage import CircuitConfig
 from repro.core.p2m_layer import P2MConfig
 from repro.core.snn import SpikingCNNConfig
@@ -33,11 +34,16 @@ Params = dict
 
 @dataclass(frozen=True)
 class P2MModelConfig:
-    """Full paper model: P²M first layer + digital spiking backbone."""
+    """Full paper model: P²M first layer + digital spiking backbone (the
+    paper's CNN, or Spikformer — repro.core.backbone)."""
     p2m: P2MConfig = field(default_factory=P2MConfig)
-    backbone: SpikingCNNConfig = field(default_factory=lambda: SpikingCNNConfig(
+    backbone: BackboneConfig = field(default_factory=lambda: SpikingCNNConfig(
         first_layer_external=True))
     coarse_window_ms: float = 1000.0     # backbone integration time (paper: ~s)
+
+    def __post_init__(self):
+        if self.backbone.kind == "spikformer":
+            self.backbone.validate(self.p2m.out_channels, self.p2m.stride)
 
     def coarsen_group(self) -> int:
         g = self.coarse_window_ms / self.p2m.t_intg_ms
@@ -48,7 +54,7 @@ class P2MModelConfig:
 def model_init(key: jax.Array, cfg: P2MModelConfig) -> tuple[Params, dict]:
     k1, k2 = jax.random.split(key)
     p2m_params = p2m_layer.p2m_init(k1, cfg.p2m)
-    bb_params, bb_state = snn.spiking_cnn_init(k2, cfg.backbone)
+    bb_params, bb_state = backbone.init(k2, cfg.backbone)
     return {"p2m": p2m_params, "backbone": bb_params}, bb_state
 
 
@@ -63,7 +69,7 @@ def model_apply(params: Params, state: dict, events: jax.Array,
     tb = snn.max_pool(tb)
     spikes1 = tb.reshape((B, T) + tb.shape[1:])
     coarse = p2m_layer.coarsen_spikes(spikes1, cfg.coarsen_group())
-    logits, new_state, aux = snn.spiking_cnn_apply(
+    logits, new_state, aux = backbone.apply(
         params["backbone"], state, coarse, cfg.backbone, train=train)
     aux["spikes/p2m"] = jax.lax.stop_gradient(jnp.sum(spikes1))
     aux["events/in"] = jax.lax.stop_gradient(jnp.sum(events))

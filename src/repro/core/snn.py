@@ -8,7 +8,7 @@ from __future__ import annotations
 import math
 from dataclasses import dataclass, field
 from functools import partial
-from typing import Any
+from typing import Any, ClassVar
 
 import jax
 import jax.numpy as jnp
@@ -160,6 +160,10 @@ class SpikingCNNConfig:
     input_hw: tuple[int, int] = (128, 128)
     lif: LIFConfig = field(default_factory=LIFConfig)
     first_layer_external: bool = False          # True when P²M supplies layer 1
+    # the backbone seam's key (repro.core.backbone); a class constant, so
+    # the config's dict and every checkpoint written before it stay as
+    # they were
+    kind: ClassVar[str] = "cnn"
 
     @property
     def n_conv(self) -> int:

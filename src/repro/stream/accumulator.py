@@ -20,8 +20,9 @@ drift, applies the fitted transfer curve + process variation, compares
 against the variant's threshold, 2x-pools the binary spikes onto the
 sensor output, accumulates them toward the backbone's coarse grid, and
 — on lanes crossing a coarse boundary — steps the stateful spiking
-backbone (core/snn.spiking_cnn_stream_step) and the rate-decoding logit
-average. The capacitor precharges (x ← 0) after every readout.
+backbone through the backbone seam (core/backbone.stream_step: the
+paper's CNN or Spikformer) and the rate-decoding logit average. The
+capacitor precharges (x ← 0) after every readout.
 
 Everything is masked per lane (``active`` / ``coarse_mask``), so one
 fixed-shape jitted step serves a continuously-batched lane table whose
@@ -47,7 +48,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro.core import analog, leakage, p2m_layer, snn
+from repro.core import analog, backbone, leakage, p2m_layer, snn
 # the SAME conv the offline curvefit forward runs — parity depends on
 # identical padding/dimension numbers, so it is imported, not copied
 from repro.core.p2m_layer import _conv
@@ -178,7 +179,7 @@ def _readout_core(state: dict, nb: dict, *, analog_cfg, bb_cfg) -> dict:
     spikes = snn.spike_fn(v_pre - nb["theta"])                # [B, H, W, C]
     pooled = snn.max_pool(spikes)
     coarse = state["coarse"] + pooled
-    logits_t, mem2 = snn.spiking_cnn_stream_step(
+    logits_t, mem2 = backbone.stream_step(
         nb["backbone"], nb["bn_state"], state["mem"], coarse, bb_cfg)
     return {"spikes": spikes, "pooled": pooled, "coarse": coarse,
             "logits_t": logits_t, "mem2": mem2}
@@ -241,7 +242,7 @@ def make_stream_fns(dep: Deployment, *, capacity: int,
             # backbone frame
             "coarse": jnp.zeros((capacity, hp, wp, C)),
             # backbone LIF membranes (per layer) + rate-decoding average
-            "mem": snn.spiking_cnn_stream_init(bb_cfg, capacity),
+            "mem": backbone.stream_init(bb_cfg, capacity),
             "logits": jnp.zeros((capacity, bb_cfg.n_classes)),
             "n_coarse": jnp.zeros((capacity,), jnp.int32),
         }
@@ -366,7 +367,7 @@ def make_multi_stream_fns(dep: Deployment, *, capacity: int,
             "x": jnp.zeros((capacity, H // p2m_cfg.stride,
                             W // p2m_cfg.stride, C)),
             "coarse": jnp.zeros((capacity, hp, wp, C)),
-            "mem": snn.spiking_cnn_stream_init(bb_cfg, capacity),
+            "mem": backbone.stream_init(bb_cfg, capacity),
             "logits": jnp.zeros((capacity, bb_cfg.n_classes)),
             "n_coarse": jnp.zeros((capacity,), jnp.int32),
         }

@@ -150,6 +150,12 @@ def make_adapt_fns(dep: Deployment, *, capacity: int, chunk_slots: int,
     multi-variant flavor: fold/readout take ``(entry, bundle)`` and
     gather each lane's base numerics before applying its deltas.
     """
+    if dep.model_cfg.backbone.kind != "cnn":
+        raise ValueError(
+            f"online adaptation steps the paper's spiking CNN in its "
+            f"per-lane readout (lane_head); a "
+            f"{dep.model_cfg.backbone.kind!r} backbone cannot adapt — "
+            f"serve it without adapt")
     if use_kernel:
         raise ValueError(
             "online adaptation requires the differentiable XLA scan "

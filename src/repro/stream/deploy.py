@@ -30,12 +30,11 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.checkpoint import store
-from repro.core import leakage, p2m_layer, snn
+from repro.core import backbone, leakage, p2m_layer, snn
 from repro.core.analog import AnalogConfig
 from repro.core.codesign import P2MModelConfig
 from repro.core.leakage import CircuitConfig, LeakageConfig
 from repro.core.p2m_layer import P2MConfig
-from repro.core.snn import LIFConfig, SpikingCNNConfig
 
 DEPLOY_SCHEMA = "p2m-stream-deploy/v1"
 
@@ -53,19 +52,17 @@ def model_config_to_dict(cfg: P2MModelConfig) -> dict:
 
 def model_config_from_dict(d: dict) -> P2MModelConfig:
     """Inverse of :func:`model_config_to_dict` (JSON round-trip safe:
-    lists are coerced back to the config tuples)."""
+    lists are coerced back to the config tuples). The backbone's ``kind``
+    picks its config class; a backbone without one is the spiking CNN
+    (:func:`repro.core.backbone.config_from_dict`)."""
     p2m = dict(d["p2m"])
     leak = dict(p2m.pop("leak"))
     leak["circuit"] = CircuitConfig(leak["circuit"])
     analog_cfg = AnalogConfig(**p2m.pop("analog"))
-    bb = dict(d["backbone"])
-    lif = LIFConfig(**bb.pop("lif"))
-    bb["channels"] = tuple(bb["channels"])
-    bb["input_hw"] = tuple(bb["input_hw"])
     return P2MModelConfig(
         p2m=P2MConfig(**p2m, analog=analog_cfg,
                       leak=LeakageConfig(**leak)),
-        backbone=SpikingCNNConfig(**bb, lif=lif),
+        backbone=backbone.config_from_dict(d["backbone"]),
         coarse_window_ms=d["coarse_window_ms"])
 
 
@@ -142,9 +139,8 @@ def offline_forward(dep: Deployment, events: jax.Array) -> dict:
     tb = snn.max_pool(spikes.reshape((B * T,) + spikes.shape[2:]))
     pooled = tb.reshape((B, T) + tb.shape[1:])
     coarse = p2m_layer.coarsen_spikes(pooled, cfg.coarsen_group())
-    logits, _, _ = snn.spiking_cnn_apply(dep.params["backbone"],
-                                         dep.bn_state, coarse, cfg.backbone,
-                                         train=False)
+    logits, _, _ = backbone.apply(dep.params["backbone"], dep.bn_state,
+                                  coarse, cfg.backbone, train=False)
     return {"spikes": spikes, "v_pre": v_pre, "pooled": pooled,
             "coarse": coarse, "logits": logits}
 

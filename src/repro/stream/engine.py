@@ -517,6 +517,7 @@ class StreamEngine:
         self.slot_us = slot_us_for(cfg.t_intg_ms, cfg.n_sub)
         self.chunk_us = self.slot_us * self.chunk_slots
         self.group = dep.model_cfg.coarsen_group()
+        self.backbone_kind = dep.model_cfg.backbone.kind
         self.use_kernel = use_kernel
         self.prefetch = prefetch
         self.adapt = adapt
@@ -984,7 +985,11 @@ class StreamEngine:
                     coarse_mask[lane_i] = \
                         (lane.windows_done + 1) % self.group == 0
                 t0 = time.perf_counter()
-                with TraceAnnotation("p2m.readout", window=window):
+                # the program steps the backbone on every lane; the model
+                # needs it only on the coarse_lanes at a coarse boundary
+                with TraceAnnotation("p2m.readout", window=window,
+                                     backbone=self.backbone_kind,
+                                     coarse_lanes=int(coarse_mask.sum())):
                     if self.adapt is None:
                         state, out = self.fns.readout(
                             state, active, jnp.asarray(coarse_mask), *extra)

@@ -1,7 +1,8 @@
 """Real-size compiles of the serving path's programs for a described TPU
 v5e, with no chip attached: the Pallas kernels at paper width (128×128
-sensor, F=16 in-pixel filters) and the jitted serving fold step with the
-fused kernel. The TPU compiler refuses here what it would refuse on the
+sensor, F=16 in-pixel filters), the jitted serving fold step with the
+fused kernel, and the readout step with Spikformer-2-256's backbone at its
+published widths. The TPU compiler refuses here what it would refuse on the
 chip — tiles off the (8, 128) grid, VMEM overruns, programs that do not
 fit — at no chip time.
 
@@ -122,3 +123,25 @@ def test_serving_fold_step_with_kernel(one_chip, monkeypatch, slots):
         state, _sds((capacity, slots, HW, HW, 2), one_chip),
         _sds((capacity,), one_chip, jnp.bool_)).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_spikformer_readout_step(one_chip):
+    """The engine's jitted readout at Spikformer-2-256's published widths
+    (8 lanes of 5.6 MiB of state, the backbone step inside) compiles for
+    the chip, and its ops carry the backbone's named scopes, which the
+    profiler's op metadata shows."""
+    from repro.configs import p2m_spikformer
+    from repro.stream import accumulator, deploy
+
+    dep = deploy.fresh_deployment(p2m_spikformer.CONFIG, seed=0)
+    capacity = 8
+    fns = accumulator.make_stream_fns(dep, capacity=capacity,
+                                      chunk_slots=S)
+    state = jax.eval_shape(fns.init_state)
+    state = jax.tree.map(lambda s: _sds(s.shape, one_chip, s.dtype), state)
+    mask = _sds((capacity,), one_chip, jnp.bool_)
+    compiled = fns.readout.lower(state, mask, mask).compile()
+    text = compiled.as_text()
+    for scope in ("p2m.sps", "p2m.encoder", "p2m.head"):
+        assert scope in text
+    assert compiled.memory_analysis().argument_size_in_bytes < 2 ** 30

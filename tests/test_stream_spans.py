@@ -172,3 +172,14 @@ def test_tracing_changes_no_answer(prefetched, dep, src):
     plain, *_ = _served(dep, src, None, n_streams=5, traced=False)
     assert [(r.stream_id, r.prediction, r.logits) for r in traced.results] \
         == [(r.stream_id, r.prediction, r.logits) for r in plain.results]
+
+
+def test_readout_counts_the_backbone_steps_the_model_needed(prefetched):
+    report, engine, _, events = prefetched
+    readouts = _stats(events, "p2m.readout")
+    assert {s["backbone"] for s in readouts} == {"cnn"}
+    # lanes at a coarse boundary, against the capacity the program steps
+    assert all(0 <= s["coarse_lanes"] <= engine.padded_capacity
+               for s in readouts)
+    assert sum(s["coarse_lanes"] for s in readouts) \
+        == sum(r.n_coarse_frames for r in report.results)
