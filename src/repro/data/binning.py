@@ -7,7 +7,9 @@ frames (ON/OFF on the last axis) at an arbitrary integration time T_INTG.
 a single recording's ``[n_total, H, W, 2]`` fine-slot histogram — one
 ``np.add.at`` scatter per chunk, never materializing the full event list
 — with integer spatial downscaling from the sensor resolution to the
-model resolution. Cache layout and keying live in repro.data.cache.
+model resolution. :func:`event_cells` is the one event → cell rule it
+shares with the serving engine's window binner (repro.stream.engine).
+Cache layout and keying live in repro.data.cache.
 """
 from __future__ import annotations
 
@@ -29,6 +31,35 @@ def slot_us_for(t_intg_ms: float, n_sub: int) -> int:
     return int(round(us))
 
 
+def event_cells(t: np.ndarray, x: np.ndarray, y: np.ndarray, p: np.ndarray,
+                *, t0_us, n_slots: int, slot_us: int,
+                sensor_hw: tuple[int, int], out_hw: tuple[int, int],
+                t_stop_us: int | None = None
+                ) -> tuple[np.ndarray, np.ndarray]:
+    """Where each event lands in an ``[n_slots, H, W, 2]`` count block:
+    the binner's one event → cell rule, shared by :func:`bin_chunks` and
+    the serving engine's window binner. Returns the keep mask over the
+    events and the flat cell index of each kept one.
+
+    ``t0_us`` is the block's first slot start, a scalar or one per
+    event. Events before it, past the last slot, or at/after
+    ``t_stop_us`` are dropped; coordinates are downscaled ``sensor →
+    out`` by integer scaling (x * W_out // W_sensor) and dropped off the
+    grid; polarity p=1 (ON) goes to channel 0, p=0 (OFF) to channel 1.
+    """
+    sh, sw = sensor_hw
+    oh, ow = out_hw
+    slot = (t - t0_us) // slot_us
+    yy = (y.astype(np.int64) * oh) // sh
+    xx = (x.astype(np.int64) * ow) // sw
+    ok = ((slot >= 0) & (slot < n_slots) & (yy >= 0) & (yy < oh)
+          & (xx >= 0) & (xx < ow))
+    if t_stop_us is not None:
+        ok &= t < t_stop_us
+    cells = ((slot * oh + yy) * ow + xx) * 2 + (1 - p.astype(np.int64))
+    return ok, cells[ok]
+
+
 def bin_chunks(chunks: Iterable[EventChunk], *, n_total: int, slot_us: int,
                sensor_hw: tuple[int, int], out_hw: tuple[int, int],
                t0_us: int = 0, t_stop_us: int | None = None) -> np.ndarray:
@@ -37,27 +68,20 @@ def bin_chunks(chunks: Iterable[EventChunk], *, n_total: int, slot_us: int,
     synthetic generator). Events before ``t0_us``, past the last slot, or
     at/after ``t_stop_us`` (a labeled window's end — events beyond it
     belong to the NEXT sample, not this one) are dropped; coordinates are
-    downscaled ``sensor → out`` by integer scaling (x * W_out // W_sensor).
+    downscaled ``sensor → out`` by integer scaling (x * W_out // W_sensor)
+    (:func:`event_cells`).
     """
-    sh, sw = sensor_hw
     oh, ow = out_hw
     frames = np.zeros((n_total, oh, ow, 2), dtype=np.float32)
+    flat = frames.reshape(-1)
     for c in chunks:
         if not len(c):
             continue
-        slot = (c.t - t0_us) // slot_us
-        ok = (slot >= 0) & (slot < n_total)
-        if t_stop_us is not None:
-            ok &= c.t < t_stop_us
-        if not ok.any():
-            continue
-        slot = slot[ok].astype(np.int64)
-        y = (c.y[ok].astype(np.int64) * oh) // sh
-        x = (c.x[ok].astype(np.int64) * ow) // sw
-        ok2 = (y >= 0) & (y < oh) & (x >= 0) & (x < ow)
-        slot, y, x = slot[ok2], y[ok2], x[ok2]
-        pol = 1 - c.p[ok][ok2].astype(np.int64)   # p=1 (ON) → channel 0
-        np.add.at(frames, (slot, y, x, pol), 1.0)
+        _, cells = event_cells(c.t, c.x, c.y, c.p, t0_us=t0_us,
+                               n_slots=n_total, slot_us=slot_us,
+                               sensor_hw=sensor_hw, out_hw=out_hw,
+                               t_stop_us=t_stop_us)
+        np.add.at(flat, cells, 1.0)
     return frames
 
 

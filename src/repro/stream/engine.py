@@ -19,18 +19,15 @@ Lifecycle of one stream (see docs/streaming.md):
      synthetic generator, replayed as timestamped raw ``(t, x, y, p)``
      chunks) and the lane's charge/membrane state zeroed (precharge) —
      resident iterators never exceed the lane capacity;
-  3. every replay tick, each occupied lane's next chunk is binned onto
-     the fine sub-slot grid (repro.data.binning semantics, sensor →
-     model downscale included) by a host-side worker thread — a
-     window's binning jobs are all submitted at the window's start, so
-     the worker bins the later chunks while the serving thread
-     assembles the earlier ones, and the first chunk of every window
-     waits for its own binning — into the window's sub-slot range of
-     one dense batch; once the window's last chunk is in, ONE host →
-     device copy and ONE jitted lane-batched ``fold`` advance every
-     lane's leak ODE + conv deposit through all of the window's
-     sub-slots together — no per-tick host sync; the window's only
-     sync point is its readout;
+  3. at each window's start every host-side bin worker takes the
+     window's replay chunks of the occupied lanes it owns and bins them
+     onto the fine sub-slot grid (repro.data.binning semantics, sensor
+     → model downscale included) in one vectorised pass, straight into
+     its lane rows of one of two reused dense window batches; once
+     every worker is done, ONE host → device copy and ONE jitted
+     lane-batched ``fold`` advance every lane's leak ODE + conv deposit
+     through all of the window's sub-slots together — no per-tick host
+     sync; the window's only sync point is its readout;
   4. at each T_INTG boundary one jitted ``readout`` comparator-reads
      every lane, accumulates pooled spikes toward the backbone coarse
      grid, and — per lane, whenever ITS coarse window completes — steps
@@ -85,8 +82,8 @@ behind the SAME single admission front — one bounded pending deque feeds
 a lane freed on any shard. Host binning scales with it: ``bin_workers``
 :class:`_BinWorker` threads each own a disjoint slice of the lane axis
 (aligned with the mesh shards when ``bin_workers == devices``) and bin
-their lanes in parallel, from the jobs submitted at each window's start
-— the multi-worker attack on the host-bound saturation knee. Sharded
+their lanes in parallel, one job each per window — the multi-worker
+attack on the host-bound saturation knee. Sharded
 serving, any worker count, and ``prefetch=False`` (the bit-identical
 inline oracle) all produce identical predictions and ledgers to the
 ``devices=1`` single-worker path (sharded logits to a few ulp).
@@ -105,6 +102,7 @@ import threading
 import time
 from collections import deque
 from dataclasses import asdict, dataclass, field
+from functools import partial
 from typing import Iterator
 
 import jax
@@ -112,7 +110,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.profiler import TraceAnnotation
 
-from repro.data.binning import bin_chunks, slot_us_for
+from repro.data.binning import event_cells, slot_us_for
 from repro.data.formats import EventChunk
 from repro.data.sources import EventSource
 from repro.serve.slots import ShardedSlots
@@ -184,10 +182,9 @@ class _Lane:
 
 class _BinWorker:
     """Single host-side worker thread binning replay chunks off the
-    serving thread (async host binning: a window's jobs are submitted at
-    its start, so while the serving thread assembles chunk ``c`` the
-    worker bins chunk ``c+1``; the window's first chunk waits for its
-    own binning). Jobs are executed strictly in
+    serving thread: one job a window, which bins the window's chunks of
+    the worker's lanes straight into their rows of the window batch
+    while the other workers bin theirs. Jobs are executed strictly in
     submission order — a lane's replay iterator is only ever advanced on
     the ONE worker that owns that lane, so chunk order per lane is
     preserved. Exceptions propagate to the consumer at ``get()``."""
@@ -248,9 +245,9 @@ class _BinPool:
     """Fixed pool of :class:`_BinWorker` threads, one per lane partition
     (the engine assigns each worker a contiguous slice of the lane axis —
     mesh-shard-aligned when ``bin_workers == devices``). The consumer
-    submits one job per worker per replay tick and gathers them in worker
-    order, so assembly — and therefore the folded frames — is
-    deterministic for any worker count."""
+    submits one job per worker per window and gathers them in worker
+    order; workers write disjoint lane rows of the window batch, so the
+    folded frames are the same for any worker count."""
 
     def __init__(self, n: int):
         self.workers = [_BinWorker(i) for i in range(n)]
@@ -442,14 +439,14 @@ class StreamEngine:
     binned, per T_INTG window (must divide ``n_sub``; default: one
     chunk per fine sub-slot, the finest arrival granularity the binned
     contract expresses). The device sees whole windows whatever it is:
-    the chunks are assembled into one ``[capacity, n_sub, H, W, 2]``
+    the chunks are binned into one ``[capacity, n_sub, H, W, 2]``
     batch, copied once and folded by one call per window.
     ``use_kernel=True`` folds the window's sub-slots through the fused
     Pallas stream_fold kernel instead of the XLA scan (identical spike
     maps and predictions, charge to a few ulp — tests/test_stream_fold.py
     pins it). ``prefetch=False`` turns
-    off the async host-binning workers and bins chunks inline on the
-    serving thread (debug aid; the folded numbers are identical).
+    off the async host-binning workers and runs their window jobs inline
+    on the serving thread (debug aid; the folded numbers are identical).
 
     ``executor`` (repro.stream.shard.LaneExecutor) shards the lane axis
     over a 1-D ``"lane"`` device mesh: the capacity pads up to a multiple
@@ -650,24 +647,6 @@ class StreamEngine:
         return _Lane(stream_id=stream_id, label=label, chunks=chunks,
                      n_windows=n_windows)
 
-    def _bin_chunk(self, source: EventSource, lane: _Lane,
-                   lane_i: int) -> np.ndarray:
-        """Next replay chunk of ``lane`` (global lane ``lane_i``) → fine
-        sub-slot frames [chunk_slots, H, W, 2] (offline-binner semantics:
-        same slot grid, same sensor → model downscale)."""
-        with TraceAnnotation("p2m.bin", stream=lane.stream_id,
-                             lane=lane_i) as span:
-            chunk = next(lane.chunks)
-            span.set_metadata(events=len(chunk))
-            lane.n_events += len(chunk)
-            frames = bin_chunks([chunk], n_total=self.chunk_slots,
-                                slot_us=self.slot_us,
-                                sensor_hw=source.sensor_hw,
-                                out_hw=self.fns.in_hw,
-                                t0_us=lane.t_cursor_us)
-        lane.t_cursor_us += self.chunk_us
-        return frames
-
     def _worker_of(self, lane: int) -> int:
         """Owning bin worker of a global lane: contiguous balanced slices
         of the padded lane axis, exactly shard-aligned when
@@ -685,15 +664,6 @@ class StreamEngine:
             parts[self._worker_of(lane_i)].append((lane_i, lane))
         return parts
 
-    def _bin_part(self, source: EventSource,
-                  lanes: list[tuple[int, _Lane]]
-                  ) -> list[tuple[int, np.ndarray]]:
-        """One worker's share of a replay tick: each owned occupied
-        lane's next chunk, binned to [chunk_slots, H, W, 2]. Runs on the
-        owning :class:`_BinWorker` thread when prefetching."""
-        return [(lane_i, self._bin_chunk(source, lane, lane_i))
-                for lane_i, lane in lanes]
-
     def _window_frames(self) -> np.ndarray:
         """A zeroed host batch for one window's fold:
         [padded_capacity, n_sub, H, W, 2] (unoccupied and mesh-padding
@@ -702,14 +672,52 @@ class StreamEngine:
         return np.zeros((self.padded_capacity, self.n_sub, h, w, 2),
                         np.float32)
 
-    def _assemble(self, parts: list[list[tuple[int, np.ndarray]]],
-                  frames: np.ndarray, chunk: int) -> None:
-        """Write the workers' per-lane blocks of replay chunk ``chunk``
-        into its sub-slot range of the window batch ``frames``."""
-        lo = chunk * self.chunk_slots
-        for part in parts:
-            for lane_i, block in part:
-                frames[lane_i, lo:lo + self.chunk_slots] = block
+    def _bin_window(self, source: EventSource,
+                    lanes: list[tuple[int, _Lane]], frames: np.ndarray,
+                    stale: np.ndarray, window: int, worker: int
+                    ) -> np.ndarray:
+        """One worker's share of a window: the next ``chunks_per_window``
+        replay chunks of each of its occupied ``lanes``, binned in one
+        pass straight into their rows of the window batch ``frames``
+        (offline-binner semantics: each chunk on its own sub-slot range,
+        same slot grid, same sensor → model downscale). First zeroes
+        ``stale``, the cells the worker wrote into ``frames`` at its last
+        use, so a freed lane reads zero and a re-admitted one only its new
+        stream. Returns the flat indices of the cells it wrote. Runs on
+        the owning :class:`_BinWorker` thread when prefetching."""
+        h, w = self.fns.in_hw
+        chunk_cells = self.chunk_slots * h * w * 2
+        lane_cells = self.n_sub * h * w * 2
+        flat = frames.reshape(-1)
+        with TraceAnnotation("p2m.bin", window=window, worker=worker,
+                             lanes=len(lanes)) as span:
+            flat[stale] = 0.0
+            chunks, t0s, bases = [], [], []
+            for lane_i, lane in lanes:
+                for c in range(self.chunks_per_window):
+                    chunk = next(lane.chunks)
+                    lane.n_events += len(chunk)
+                    chunks.append(chunk)
+                    t0s.append(lane.t_cursor_us)
+                    bases.append(lane_i * lane_cells + c * chunk_cells)
+                    lane.t_cursor_us += self.chunk_us
+            sizes = [len(c) for c in chunks]
+            cells = np.zeros(0, np.int64)
+            if sum(sizes):
+                t, x, y, p = (np.concatenate([getattr(c, f) for c in chunks])
+                              for f in ("t", "x", "y", "p"))
+                ok, cells = event_cells(
+                    t, x, y, p, t0_us=np.repeat(t0s, sizes),
+                    n_slots=self.chunk_slots, slot_us=self.slot_us,
+                    sensor_hw=source.sensor_hw, out_hw=(h, w))
+                cells += np.repeat(bases, sizes)[ok]
+                # counted by sort, not np.add.at: each cell once, so one
+                # plain assignment writes the window's counts
+                cells, counts = np.unique(cells, return_counts=True)
+                flat[cells] = counts
+            span.set_metadata(events=sum(sizes), cells=len(cells),
+                              cleared=len(stale))
+        return cells
 
     # ------------------------------------------------------------------
     def serve(self, source: EventSource, n_streams: int, *, seed: int = 0,
@@ -805,6 +813,11 @@ class StreamEngine:
               (jnp.zeros((self.padded_capacity,), jnp.int32), self._bundle))
         wmask = jnp.zeros((self.padded_capacity,), bool)
         wframes = jnp.asarray(self._window_frames())
+        # the two window batches, written in turn, and per batch and bin
+        # worker the flat indices of the cells it last wrote there
+        batches = [self._window_frames() for _ in range(2)]
+        written = [[np.zeros(0, np.int64)] * self.bin_workers
+                   for _ in range(2)]
         if self.adapt is None:
             ws = self.fns.fold(self.fns.init_state(), wframes, wmask, *wx)
             ws, _ = self.fns.readout(ws, wmask, wmask, *wx)
@@ -926,59 +939,52 @@ class StreamEngine:
                                          late_ms=max(0.0, -delay) * 1e3):
                         if delay > 0:
                             time.sleep(delay)
-                # ---- fold the window's replay chunks ------------------
-                # the window's binning jobs are all submitted now, so the
-                # workers (each on its own lane slice, in parallel) bin
-                # the later chunks while this thread assembles the
-                # earlier ones into the window's batch; the batch then
-                # goes to the device in one copy and one fold dispatch,
-                # left in flight — the window's only host↔device sync is
-                # the readout below
-                parts_by_worker = self._partition(occupied)
+                # ---- bin and fold the window -------------------------
+                # one job per bin worker, each on its own lane slice, in
+                # parallel, straight into this window's batch; the batch
+                # then goes to the device in one copy and one fold
+                # dispatch, left in flight — the window's only host↔device
+                # sync is the readout below. Batch window % 2 is written
+                # again at window + 2, after window + 1's readout sync:
+                # that sync waits on fold window + 1, whose state is fold
+                # window's, the last reader of this batch (its copy
+                # included, even where jnp.asarray aliases the buffer, as
+                # it may on the CPU)
+                frames = batches[window % 2]
+                stale = written[window % 2]
+                jobs = [partial(self._bin_window, source, lanes, frames,
+                                stale[wi], window, wi)
+                        for wi, lanes in enumerate(self._partition(occupied))]
+                t0 = time.perf_counter()
                 if pool is not None:
-                    for _ in range(self.chunks_per_window):
-                        for wi, lanes in enumerate(parts_by_worker):
-                            pool.submit(wi, lambda ls=lanes:
-                                        self._bin_part(source, ls))
-                last = self.chunks_per_window - 1
-                for chunk in range(self.chunks_per_window):
-                    t0 = time.perf_counter()
-                    with TraceAnnotation("p2m.bin_wait", window=window,
-                                         chunk=chunk):
-                        parts = ([pool.get(wi)
-                                  for wi in range(self.bin_workers)]
-                                 if pool is not None else
-                                 [self._bin_part(source, ls)
-                                  for ls in parts_by_worker])
-                    with TraceAnnotation("p2m.assemble", window=window,
-                                         chunk=chunk, lanes=len(occupied)):
-                        if chunk == 0:
-                            # a fresh batch every window, never reused:
-                            # the last window's transfer may still read
-                            # its own (on the CPU jnp.asarray may alias
-                            # it), freed only as this one replaces it
-                            frames = self._window_frames()
-                        self._assemble(parts, frames, chunk)
-                    if chunk == last:
-                        with TraceAnnotation(
-                                "p2m.h2d", window=window,
-                                chunks=self.chunks_per_window,
-                                slots=self.n_sub, bytes=frames.nbytes):
-                            frames_dev = jnp.asarray(frames)
-                        with TraceAnnotation(
-                                "p2m.fold", window=window,
-                                chunks=self.chunks_per_window,
-                                slots=self.n_sub):
-                            if self.adapt is None:
-                                state = self.fns.fold(state, frames_dev,
-                                                      active, *extra)
-                            else:
-                                state, self.adapt_state = self.fns.fold(
-                                    state, self.adapt_state, frames_dev,
-                                    active, *extra)
-                    # one entry per chunk: its bin wait and assembly, and
-                    # on the last the window's copy and fold dispatch
-                    report.fold_s.append(time.perf_counter() - t0)
+                    for wi, job in enumerate(jobs):
+                        pool.submit(wi, job)
+                with TraceAnnotation("p2m.bin_wait", window=window):
+                    stale[:] = ([pool.get(wi)
+                                 for wi in range(self.bin_workers)]
+                                if pool is not None else
+                                [job() for job in jobs])
+                with TraceAnnotation(
+                        "p2m.h2d", window=window,
+                        chunks=self.chunks_per_window,
+                        slots=self.n_sub, bytes=frames.nbytes):
+                    frames_dev = jnp.asarray(frames)
+                with TraceAnnotation(
+                        "p2m.fold", window=window,
+                        chunks=self.chunks_per_window,
+                        slots=self.n_sub):
+                    if self.adapt is None:
+                        state = self.fns.fold(state, frames_dev, active,
+                                              *extra)
+                    else:
+                        state, self.adapt_state = self.fns.fold(
+                            state, self.adapt_state, frames_dev, active,
+                            *extra)
+                # one entry per replay chunk, each an equal share of the
+                # window's feed: the bin wait, the copy, the fold dispatch
+                report.fold_s.extend(
+                    [(time.perf_counter() - t0) / self.chunks_per_window]
+                    * self.chunks_per_window)
                 # ---- readout at the T_INTG boundary -------------------
                 coarse_mask = np.zeros((self.padded_capacity,), bool)
                 for lane_i, lane in occupied:

@@ -29,8 +29,7 @@ from bench import trace as trace_mod  # noqa: E402
 
 HW = 16
 SPANS = ("p2m.schedule", "p2m.admit", "p2m.pace", "p2m.bin", "p2m.bin_wait",
-         "p2m.assemble", "p2m.h2d", "p2m.fold", "p2m.readout", "p2m.sync",
-         "p2m.finalise")
+         "p2m.h2d", "p2m.fold", "p2m.readout", "p2m.sync", "p2m.finalise")
 
 
 @pytest.fixture(scope="module")
@@ -104,11 +103,11 @@ def test_every_span_counts_its_work(run, request):
     windows = max(r.finished_window for r in report.results)
     chunks = windows * engine.chunks_per_window
     want = {"p2m.schedule": windows, "p2m.readout": windows,
-            "p2m.sync": windows, "p2m.bin_wait": chunks,
-            "p2m.assemble": chunks, "p2m.h2d": windows, "p2m.fold": windows,
+            "p2m.sync": windows, "p2m.bin_wait": windows,
+            "p2m.h2d": windows, "p2m.fold": windows,
             "p2m.admit": report.n_admitted,
             "p2m.finalise": len(report.results),
-            "p2m.bin": report.total_readouts * engine.chunks_per_window,
+            "p2m.bin": windows * engine.bin_workers,
             "p2m.pace": windows if report.paced else 0}
     assert {k: _count(tr, k) for k in SPANS} == want
     assert all(want[k] > 0 for k in SPANS if k != "p2m.pace")
@@ -121,9 +120,18 @@ def test_every_span_counts_its_work(run, request):
         assert sum(s["chunks"] for s in stats) == chunks
         assert {(s["chunks"], s["slots"]) for s in stats} \
             == {(engine.chunks_per_window, engine.n_sub)}
-    # the counts sit on the spans where the work happens
-    assert sum(s["events"] for s in _stats(events, "p2m.bin")) \
-        == report.total_events
+    # the counts sit on the spans where the work happens: one bin job
+    # per worker a window, over every occupied lane of it
+    bins = _stats(events, "p2m.bin")
+    assert sum(s["events"] for s in bins) == report.total_events
+    assert sum(s["lanes"] for s in bins) == report.total_readouts
+    assert all(0 < s["cells"] <= s["events"] for s in bins if s["lanes"])
+    # a job clears what its worker wrote into the same batch two
+    # windows earlier; the first use of each batch finds nothing
+    cells = {s["window"]: s["cells"] for s in bins}
+    assert [s["cleared"] for s in sorted(bins, key=lambda s: s["window"])] \
+        == [cells.get(s["window"] - 2, 0)
+            for s in sorted(bins, key=lambda s: s["window"])]
     sched = _stats(events, "p2m.schedule")
     assert sum(s["n_admitted"] for s in sched) == report.n_admitted
     assert sum(s["n_shed"] for s in sched) == report.n_shed
@@ -163,8 +171,10 @@ def test_spans_carry_the_ids_of_what_caused_them(prefetched):
                 for s in _stats(events, "p2m.finalise")}
     assert finished == {(r.stream_id, r.finished_window - 1)
                         for r in report.results}
-    lanes = {s["lane"] for s in _stats(events, "p2m.bin")}
-    assert lanes == {lane for *_, lane in admitted}
+    windows = max(r.finished_window for r in report.results)
+    assert sorted((s["window"], s["worker"])
+                  for s in _stats(events, "p2m.bin")) \
+        == [(w, 0) for w in range(windows)]
 
 
 def test_tracing_changes_no_answer(prefetched, dep, src):

@@ -919,7 +919,7 @@ def test_one_fold_per_window_matches_per_chunk_folds(
 
     def counted(fold, frames_at):
         def call(*args):
-            seen.append(np.asarray(args[frames_at]))
+            seen.append(np.array(args[frames_at]))
             return fold(*args)
         return call
 
