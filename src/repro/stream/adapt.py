@@ -11,8 +11,9 @@ analogue of the unfrozen training protocol.
 
 Mechanics
 ---------
-Each serving lane (= one physical sensor) carries an :class:`AdaptState`
-row on the ``[capacity, ...]`` lane axis:
+Each serving lane (= one physical sensor) carries a row of
+``state["adapt"]``, the adaptation tensors that ride in the serving
+state on the ``[capacity, ...]`` lane axis:
 
 - ``dw``/``dtheta`` — the lane's persistent weight/threshold deltas,
   applied as ``quantize(w_base + dw)`` (straight-through, the same
@@ -49,12 +50,17 @@ like the serving state, per-lane updates provably never perturb other
 lanes, and registry serving gathers each lane's base numerics from the
 stacked entry bundle before applying that lane's deltas.
 
-Adaptation is a *separate opt-in compiled surface*: with
-``StreamEngine(adapt=None)`` none of this module runs and serving stays
-IEEE-bit-identical to the frozen path. The fused Pallas fold
-(``kernels/stream_fold``) has no VJP and shares one weight tensor across
-lanes, so ``use_kernel=True`` + adaptation raises (pinned by
-tests/test_stream_adapt.py). Adapted lanes are harvested through
+:func:`make_adapt_fns` returns the frozen builder's surface
+(repro.stream.accumulator.StreamFns) with the same call shape and the
+same state helpers: ``fold(state, frames, active, *extra)`` and
+``readout(state, active, coarse_mask, *extra)``, whose ``extra`` ends
+with each lane's stream label (−1: unlabeled). It adds only the
+per-lane re-linearisation, the event accumulator, the local update and
+``reset_lane_full``. It is opt-in: with ``StreamEngine(adapt=None)``
+none of this module runs and serving stays IEEE-bit-identical to the
+frozen path. The fused Pallas fold (``kernels/stream_fold``) has no VJP
+and shares one weight tensor across lanes, so ``use_kernel=True`` +
+adaptation raises (pinned by tests/test_stream_adapt.py). Adapted lanes are harvested through
 ``StreamEngine.harvest`` and round-trip as validated checkpoint deltas
 (repro.stream.deploy.save_adapt_delta) into new registry entries.
 """
@@ -72,18 +78,14 @@ from repro.core import analog, snn
 # same conv as the serving fold/offline curvefit — gradient parity
 # depends on identical padding/dimension numbers
 from repro.core.p2m_layer import _conv
-from repro.stream.accumulator import (_mask, entry_numerics,
-                                      make_multi_stream_fns,
-                                      make_stream_fns,
-                                      relinearized_numerics)
+from repro.stream.accumulator import (StreamFns, _mask, entry_numerics,
+                                      init_state, lane_executor, read_out,
+                                      relinearized_numerics, reset_lane,
+                                      zero_lane)
 from repro.stream.deploy import Deployment
 from repro.stream.shard import P_LANE, P_REP, LaneExecutor
 
 RULES = ("surrogate", "reward")
-
-# per-stream transients: reset at every admission. dw/dtheta/n_updates
-# persist across streams on a lane and reset only on entry rebind.
-_TRANSIENT = ("elig_w", "elig_theta", "ev")
 
 
 @dataclass(frozen=True)
@@ -108,22 +110,12 @@ class AdaptConfig:
 
 
 @dataclass(frozen=True)
-class AdaptFns:
-    """Jitted adaptation-enabled serving steps — the drop-in replacement
-    for StreamFns/MultiStreamFns when a StreamEngine runs with
-    ``adapt=``. ``fold``/``readout`` thread the :class:`AdaptState` dict
-    alongside the serving state; registry engines append the usual
-    ``(entry, bundle)`` pair (bundle extended with per-entry
-    ``LeakCoeffs`` — :func:`adapt_entry_numerics`)."""
-    init_state: Callable[[], dict]
-    init_adapt: Callable[[], dict]
-    reset_lane: Callable[..., dict]
-    reset_lane_transient: Callable[..., dict]
-    reset_lane_full: Callable[..., dict]
-    fold: Callable[..., tuple]
-    readout: Callable[..., tuple]
-    in_hw: tuple[int, int]
-    n_classes: int
+class AdaptFns(StreamFns):
+    """The serving surface of an adapting engine: :class:`StreamFns`
+    whose state carries ``state["adapt"]``. ``reset_lane`` keeps a
+    lane's learned deltas over stream turnover; ``reset_lane_full``
+    zeroes them too, for a lane rebound to another entry uid."""
+    reset_lane_full: Callable[[dict, int], dict]
 
 
 def adapt_entry_numerics(dep: Deployment) -> dict:
@@ -136,7 +128,14 @@ def adapt_entry_numerics(dep: Deployment) -> dict:
     return {**entry_numerics(dep), "coeffs": dep.coeffs}
 
 
-def make_adapt_fns(dep: Deployment, *, capacity: int, chunk_slots: int,
+@jax.jit
+def reset_lane_full(state: dict, lane: jax.Array) -> dict:
+    """Lane rebinds to a different entry uid: deltas learned against
+    the old base are meaningless — zero everything."""
+    return jax.tree.map(lambda v: zero_lane(v, lane), state)
+
+
+def make_adapt_fns(dep: Deployment, *, capacity: int,
                    adapt: AdaptConfig, use_kernel: bool = False,
                    executor: LaneExecutor | None = None,
                    registry: bool = False) -> AdaptFns:
@@ -146,9 +145,10 @@ def make_adapt_fns(dep: Deployment, *, capacity: int, chunk_slots: int,
     (same masking, same state update) but is vmapped per lane so each
     lane serves under its OWN ``quantize(w_base + dw)`` /
     ``theta_base + dtheta`` numerics, re-linearized through
-    ``relinearized_numerics`` each fold. ``registry=True`` builds the
-    multi-variant flavor: fold/readout take ``(entry, bundle)`` and
-    gather each lane's base numerics before applying its deltas.
+    ``relinearized_numerics`` each fold. ``extra`` is the frozen mode's
+    (``()``, or ``(entry, bundle)`` with ``registry=True``) followed by
+    the per-lane ``labels`` ``[capacity] int32``; registry lanes gather
+    their base numerics from the bundle before applying their deltas.
     """
     if dep.model_cfg.backbone.kind != "cnn":
         raise ValueError(
@@ -162,12 +162,7 @@ def make_adapt_fns(dep: Deployment, *, capacity: int, chunk_slots: int,
             "fold: kernels/stream_fold has no VJP and shares one weight "
             "tensor across lanes — serve with use_kernel=False, or drop "
             "adapt")
-    # serving-state init/reset (and the lane-axis divisibility checks)
-    # are identical to the frozen engine's — reuse them.
-    base = (make_multi_stream_fns if registry else make_stream_fns)(
-        dep, capacity=capacity, chunk_slots=chunk_slots,
-        use_kernel=False, executor=executor)
-    ex = executor or LaneExecutor()
+    ex = lane_executor(executor, capacity)
     cfg = dep.model_cfg
     p2m_cfg, bb_cfg = cfg.p2m, cfg.backbone
     analog_cfg = p2m_cfg.analog
@@ -180,30 +175,15 @@ def make_adapt_fns(dep: Deployment, *, capacity: int, chunk_slots: int,
     nb0 = adapt_entry_numerics(dep)
     nb_ax = 0 if registry else None
 
-    def init_adapt() -> dict:
-        return {
+    def init_adapt_state() -> dict:
+        return {**init_state(cfg, capacity), "adapt": {
             "dw": jnp.zeros((capacity, k, k, cin, F)),
             "dtheta": jnp.zeros((capacity,)),
             "elig_w": jnp.zeros((capacity, k, k, cin, F)),
             "elig_theta": jnp.zeros((capacity,)),
             "ev": jnp.zeros((capacity, F, H, W, cin)),
             "n_updates": jnp.zeros((capacity,), jnp.int32),
-        }
-
-    @jax.jit
-    def reset_lane_transient(astate: dict, lane: jax.Array) -> dict:
-        """New stream on the lane: clear the window accumulator and the
-        eligibility traces, KEEP the lane's learned deltas."""
-        return {key: (v.at[lane].set(jnp.zeros_like(v[lane]))
-                      if key in _TRANSIENT else v)
-                for key, v in astate.items()}
-
-    @jax.jit
-    def reset_lane_full(astate: dict, lane: jax.Array) -> dict:
-        """Lane rebinds to a different entry uid: deltas learned against
-        the old base are meaningless — zero everything."""
-        return jax.tree.map(
-            lambda v: v.at[lane].set(jnp.zeros_like(v[lane])), astate)
+        }}
 
     def lane_relin(nb: dict, dw: jax.Array, dtheta: jax.Array) -> dict:
         """One lane's adapted numerics through the differentiable seam."""
@@ -214,17 +194,21 @@ def make_adapt_fns(dep: Deployment, *, capacity: int, chunk_slots: int,
     vrelin = jax.vmap(lane_relin, in_axes=(nb_ax, 0, 0))
     vconv = jax.vmap(lambda ev, w: _conv(ev[None], w, stride)[0])
 
-    def _lane_nbs(extra: tuple) -> dict:
+    def _lanes(extra: tuple) -> tuple[dict, jax.Array]:
+        """Each lane's base numerics and stream label, from the step's
+        trailing ``(*registry, labels)``."""
+        *reg, labels = extra
         if registry:
-            entry, bundle = extra
-            return jax.tree.map(lambda leaf: leaf[entry], bundle)
-        return nb0
+            entry, bundle = reg
+            return jax.tree.map(lambda leaf: leaf[entry], bundle), labels
+        return nb0, labels
 
-    def fold_body(state: dict, astate: dict, frames: jax.Array,
-                  active: jax.Array, *extra) -> tuple[dict, dict]:
+    def fold_body(state: dict, frames: jax.Array, active: jax.Array,
+                  *extra) -> dict:
         """The scan fold under per-lane adapted numerics, plus the
         per-filter event accumulator ``E`` riding the same decay."""
-        nb = _lane_nbs(extra)
+        nb, _ = _lanes(extra)
+        astate = state["adapt"]
         ln = vrelin(nb, astate["dw"], astate["dtheta"])
         w_q, a = ln["w_q"], ln["a"]          # [cap,k,k,2,F], [cap,F]
 
@@ -236,8 +220,8 @@ def make_adapt_fns(dep: Deployment, *, capacity: int, chunk_slots: int,
 
         (x, E), _ = lax.scan(sub_step, (state["x"], astate["ev"]),
                              jnp.moveaxis(frames, 1, 0))
-        return ({**state, "x": _mask(active, x, state["x"])},
-                {**astate, "ev": _mask(active, E, astate["ev"])})
+        return {**state, "x": _mask(active, x, state["x"]),
+                "adapt": {**astate, "ev": _mask(active, E, astate["ev"])}}
 
     def lane_head(x_lin: jax.Array, ln: dict, nb: dict,
                   coarse: jax.Array, mem) -> dict:
@@ -278,28 +262,16 @@ def make_adapt_fns(dep: Deployment, *, capacity: int, chunk_slots: int,
     vgrad = jax.vmap(jax.grad(lane_loss, argnums=(0, 1), has_aux=True),
                      in_axes=(0, 0, 0, nb_ax, 0, 0, 0))
 
-    def readout_body(state: dict, astate: dict, active: jax.Array,
-                     coarse_mask: jax.Array, labels: jax.Array,
-                     *extra) -> tuple[dict, dict, dict]:
+    def readout_body(state: dict, active: jax.Array,
+                     coarse_mask: jax.Array, *extra) -> tuple[dict, dict]:
         """Frozen-engine readout semantics under per-lane numerics, then
         one local update on lanes crossing a labeled coarse boundary."""
-        nb = _lane_nbs(extra)
+        nb, labels = _lanes(extra)
+        astate = state["adapt"]
         ro = vserve(astate["dw"], astate["dtheta"], nb, state["x"],
                     state["coarse"], state["mem"])
-        spikes, pooled, coarse = ro["spikes"], ro["pooled"], ro["coarse"]
-        logits_t, mem2 = ro["logits_t"], ro["mem2"]
-        new_state = {
-            "x": _mask(active, jnp.zeros_like(state["x"]), state["x"]),
-            "coarse": _mask(active,
-                            _mask(coarse_mask, jnp.zeros_like(coarse),
-                                  coarse),
-                            state["coarse"]),
-            "mem": jax.tree.map(lambda n, o: _mask(coarse_mask, n, o),
-                                mem2, state["mem"]),
-            "logits": state["logits"] + _mask(coarse_mask, logits_t,
-                                              jnp.zeros_like(logits_t)),
-            "n_coarse": state["n_coarse"] + coarse_mask.astype(jnp.int32),
-        }
+        new_state, out = read_out(state, ro, active, coarse_mask)
+        logits_t = ro["logits_t"]
 
         # ---- local update (per lane, lane-diagonal) ----
         has_label = labels >= 0
@@ -342,27 +314,18 @@ def make_adapt_fns(dep: Deployment, *, capacity: int, chunk_slots: int,
                         astate["ev"]),
             "n_updates": astate["n_updates"] + upd.astype(jnp.int32),
         }
-        out = {"spikes": spikes,
-               "n_spikes": jnp.sum(pooled, axis=(1, 2, 3))
-               * active.astype(pooled.dtype)}
-        return new_state, new_astate, out
+        return {**new_state, "adapt": new_astate}, out
 
-    extra_specs = (P_LANE, P_REP) if registry else ()
-    fold = jax.jit(ex.shard(
-        fold_body,
-        in_specs=(P_LANE, P_LANE, P_LANE, P_LANE) + extra_specs,
-        out_specs=(P_LANE, P_LANE)))
-    readout = jax.jit(ex.shard(
-        readout_body,
-        in_specs=(P_LANE, P_LANE, P_LANE, P_LANE, P_LANE) + extra_specs,
-        out_specs=(P_LANE, P_LANE, P_LANE)))
+    specs = ((P_LANE, P_LANE, P_LANE) + ((P_LANE, P_REP) if registry else ())
+             + (P_LANE,))
+    fold = jax.jit(ex.shard(fold_body, in_specs=specs, out_specs=P_LANE))
+    readout = jax.jit(ex.shard(readout_body, in_specs=specs,
+                               out_specs=(P_LANE, P_LANE)))
 
-    return AdaptFns(init_state=base.init_state, init_adapt=init_adapt,
-                    reset_lane=base.reset_lane,
-                    reset_lane_transient=reset_lane_transient,
-                    reset_lane_full=reset_lane_full,
-                    fold=fold, readout=readout,
-                    in_hw=base.in_hw, n_classes=base.n_classes)
+    return AdaptFns(init_state=init_adapt_state, reset_lane=reset_lane,
+                    reset_lane_full=reset_lane_full, fold=fold,
+                    readout=readout, entry_numerics=adapt_entry_numerics,
+                    in_hw=(H, W), n_classes=bb_cfg.n_classes)
 
 
 def lane_stats(astate: dict) -> list[dict]:

@@ -64,14 +64,21 @@ repro.stream.adapt) turns on per-lane online plasticity: each lane
 carries persistent weight/threshold deltas that a local
 surrogate-gradient or reward-modulated rule updates at every labeled
 coarse-window readout, compensating per-device leak drift in place.
-The deltas survive stream turnover on a lane, reset when the lane
-rebinds to a different registry entry uid, and are harvested via
-:meth:`StreamEngine.harvest` into validated delta checkpoints
-(repro.stream.deploy.save_adapt_delta) that re-register as new entries.
+The deltas ride in the engine's device lane state (``state["adapt"]``,
+resident across ``serve`` calls), survive stream turnover on a lane,
+reset when the lane rebinds to a different registry entry uid, and are
+harvested via :meth:`StreamEngine.harvest` into validated delta
+checkpoints (repro.stream.deploy.save_adapt_delta) that re-register as
+new entries.
 ``adapt=None`` (the default) compiles none of this — frozen serving is
 IEEE-bit-identical to the adaptation-less engine — and the v5 artifact
 carries the ``adaptation`` block (rule, per-lane update counts and
 delta norms, pre/post-accuracy split) either way.
+
+Every mode calls the same two jitted steps once a window,
+``fold(state, frames, active, *extra)`` and
+``readout(state, active, coarse_mask, *extra)``; only the trailing
+per-window arguments ``extra`` differ (repro.stream.accumulator).
 
 **Sharded mode** (``StreamEngine(executor=LaneExecutor(devices=n))``,
 CLI ``--devices``) maps the lane axis onto a 1-D ``"lane"`` device mesh
@@ -114,10 +121,8 @@ from repro.data.binning import event_cells, slot_us_for
 from repro.data.formats import EventChunk
 from repro.data.sources import EventSource
 from repro.serve.slots import ShardedSlots
-from repro.stream.accumulator import (entry_numerics, make_multi_stream_fns,
-                                      make_stream_fns, stack_entries)
-from repro.stream.adapt import (AdaptConfig, adapt_entry_numerics,
-                                lane_stats, make_adapt_fns)
+from repro.stream.accumulator import make_stream_fns, stack_entries
+from repro.stream.adapt import AdaptConfig, lane_stats, make_adapt_fns
 from repro.stream.deploy import Deployment
 from repro.stream.registry import (Registry, RegistryEntry, compat_digest,
                                    compat_key)
@@ -420,8 +425,8 @@ class StreamEngine:
     registered entry anchors the shared serving geometry (compat key);
     per-lane numerics live in a fixed-size param table of ``max_entries``
     slots whose stacked bundle is an *argument* of the jitted
-    multi-variant fold/readout (repro.stream.accumulator
-    .make_multi_stream_fns) — so ``register``/``retire`` on the live
+    fold/readout (``make_stream_fns(..., registry=True)`` in
+    repro.stream.accumulator) — so ``register``/``retire`` on the live
     registry (hot-swap) re-stacks the bundle without recompiling and
     without perturbing lanes bound to other entries. Admission resolves
     each stream's variant request (``serve(..., variants=...)``) against
@@ -518,22 +523,16 @@ class StreamEngine:
         self.use_kernel = use_kernel
         self.prefetch = prefetch
         self.adapt = adapt
-        # adaptation re-linearizes the leak per lane at every readout,
-        # so adapting engines carry each entry's LeakCoeffs in the
-        # bundle (extra replicated scalars; frozen engines keep the
-        # exact PR 9 bundle and compiled program)
-        self._nb_fn = (adapt_entry_numerics if adapt is not None
-                       else entry_numerics)
+        build = (make_stream_fns if adapt is None
+                 else partial(make_adapt_fns, adapt=adapt))
+        self.fns = build(dep, capacity=self.padded_capacity,
+                         use_kernel=use_kernel, executor=self.executor,
+                         registry=self.registry is not None)
+        # the device lane state, resident across serve() calls: a lane
+        # keeps its learned adaptation deltas over stream turnover and
+        # harvest works after the run; admission resets each lane
+        self.state = self.fns.init_state()
         if adapt is not None:
-            self.fns = make_adapt_fns(
-                dep, capacity=self.padded_capacity,
-                chunk_slots=self.chunk_slots, adapt=adapt,
-                use_kernel=use_kernel, executor=self.executor,
-                registry=self.registry is not None)
-            # per-lane deltas/traces, resident across serve() calls so
-            # a lane keeps learning over stream turnover and harvest
-            # works after the run
-            self.adapt_state = self.fns.init_adapt()
             # entry uid each lane's deltas were learned against (-1 =
             # never admitted): rebinding to a different uid voids them
             self._lane_entry_uid = np.full((self.padded_capacity,), -1,
@@ -542,23 +541,13 @@ class StreamEngine:
                 [None] * self.padded_capacity
             self._lane_base_name = ["default"] * self.padded_capacity
             self._labels = np.full((self.padded_capacity,), -1, np.int32)
-        elif self.registry is not None:
-            self.fns = make_multi_stream_fns(
-                dep, capacity=self.padded_capacity,
-                chunk_slots=self.chunk_slots, use_kernel=use_kernel,
-                executor=self.executor)
-        else:
-            self.fns = make_stream_fns(dep, capacity=self.padded_capacity,
-                                       chunk_slots=self.chunk_slots,
-                                       use_kernel=use_kernel,
-                                       executor=self.executor)
         if self.registry is not None:
             # fixed-size per-entry param table: slot i holds the numerics
             # of one (name, uid) registration; refcounts track how many
             # resident lanes are bound to it, so hot-swap keeps a retired
             # entry's weights until its last lane drains. Unused slots
             # hold the anchor's numerics as shape placeholders.
-            anchor_nb = self._nb_fn(dep)
+            anchor_nb = self.fns.entry_numerics(dep)
             self._entry_slots: list[tuple[str, int] | None] = \
                 [None] * self.max_entries
             self._entry_refs = [0] * self.max_entries
@@ -604,7 +593,7 @@ class StreamEngine:
                 f"(bound: {[k for k in self._entry_slots if k]}) — raise "
                 f"max_entries to co-serve more variants")
         self._entry_slots[victim] = key
-        self._entry_nbs[victim] = self._nb_fn(entry.dep)
+        self._entry_nbs[victim] = self.fns.entry_numerics(entry.dep)
         self._entry_refs[victim] = 1
         self._bundle = stack_entries(self._entry_nbs)
         return victim
@@ -612,6 +601,17 @@ class StreamEngine:
     def _unbind_entry(self, slot: int) -> None:
         assert self._entry_refs[slot] > 0
         self._entry_refs[slot] -= 1
+
+    def _window_extra(self) -> tuple:
+        """The serving mode's trailing step arguments for one window:
+        the registry's per-lane entry indices and (possibly just
+        re-stacked) param bundle — same shapes, no recompile — then
+        adaptation's per-lane stream labels."""
+        extra = (() if self.registry is None else
+                 (jnp.asarray(self._entry_of), self._bundle))
+        if self.adapt is not None:
+            extra += (jnp.asarray(self._labels),)
+        return extra
 
     # ------------------------------------------------------------------
     def open_stream(self, source: EventSource, key: jax.Array,
@@ -778,7 +778,7 @@ class StreamEngine:
         slots: ShardedSlots[_Lane] = ShardedSlots(self.capacity,
                                                   self.executor.devices)
         pending: deque[tuple[int, int]] = deque()  # (stream_id, offered_w)
-        state = self.fns.init_state()
+        state = self.state
         results: list[StreamResult] = []
         report = ServingReport(
             results=results, deployed=self.dep.deployed_meta(),
@@ -806,11 +806,11 @@ class StreamEngine:
                 report.entry_rows.append(rows[k])
             return rows[k]
 
-        # warmup: compile fold/readout on a throwaway state so the
-        # latency percentiles measure steady-state serving, not jit; the
-        # fold compiles at the one shape serving dispatches, a window
-        wx = (() if self.registry is None else
-              (jnp.zeros((self.padded_capacity,), jnp.int32), self._bundle))
+        # warmup: compile fold/readout with every lane masked off, which
+        # leaves the state's values as they are, so the latency
+        # percentiles measure steady-state serving, not jit; the fold
+        # compiles at the one shape serving dispatches, a window
+        wx = self._window_extra()
         wmask = jnp.zeros((self.padded_capacity,), bool)
         wframes = jnp.asarray(self._window_frames())
         # the two window batches, written in turn, and per batch and bin
@@ -818,15 +818,8 @@ class StreamEngine:
         batches = [self._window_frames() for _ in range(2)]
         written = [[np.zeros(0, np.int64)] * self.bin_workers
                    for _ in range(2)]
-        if self.adapt is None:
-            ws = self.fns.fold(self.fns.init_state(), wframes, wmask, *wx)
-            ws, _ = self.fns.readout(ws, wmask, wmask, *wx)
-        else:
-            wl = jnp.full((self.padded_capacity,), -1, jnp.int32)
-            ws, wa = self.fns.fold(self.fns.init_state(),
-                                   self.fns.init_adapt(), wframes, wmask,
-                                   *wx)
-            ws, wa, _ = self.fns.readout(ws, wa, wmask, wmask, wl, *wx)
+        ws = self.fns.fold(state, wframes, wmask, *wx)
+        ws, _ = self.fns.readout(ws, wmask, wmask, *wx)
         jax.block_until_ready(ws["logits"])
         pool = _BinPool(self.bin_workers) if self.prefetch else None
         next_offer = 0
@@ -893,7 +886,7 @@ class StreamEngine:
                                 lane.entry_uid = entry.uid
                                 lane.entry_slot = slot_e
                                 self._entry_of[lane_i] = slot_e
-                            state = self.fns.reset_lane(state, lane_i)
+                            reset = self.fns.reset_lane
                             if self.adapt is not None:
                                 # learned deltas persist across streams on
                                 # the lane (it models one physical sensor)
@@ -901,20 +894,15 @@ class StreamEngine:
                                 # entry
                                 uid = (entry.uid if self.registry is not None
                                        else 0)
-                                if self._lane_entry_uid[lane_i] == uid:
-                                    self.adapt_state = \
-                                        self.fns.reset_lane_transient(
-                                            self.adapt_state, lane_i)
-                                else:
-                                    self.adapt_state = \
-                                        self.fns.reset_lane_full(
-                                            self.adapt_state, lane_i)
+                                if self._lane_entry_uid[lane_i] != uid:
+                                    reset = self.fns.reset_lane_full
                                 self._lane_entry_uid[lane_i] = uid
                                 self._lane_base[lane_i] = (
                                     entry.dep if self.registry is not None
                                     else self.dep)
                                 self._lane_base_name[lane_i] = lane.entry_name
                                 self._labels[lane_i] = lane.label
+                            state = reset(state, lane_i)
                             report.n_admitted += 1
                             row_of(lane)["n_admitted"] += 1
                             report.per_shard_admitted[
@@ -923,12 +911,7 @@ class StreamEngine:
                                                   slots.n_occupied)
                     occupied = list(slots.occupied())
                     active = jnp.asarray(slots.active_mask())
-                    # registry mode: this window's per-lane entry indices
-                    # + the (possibly just re-stacked) param bundle ride
-                    # along as jitted-step arguments — same shapes, no
-                    # recompile
-                    extra = (() if self.registry is None else
-                             (jnp.asarray(self._entry_of), self._bundle))
+                    extra = self._window_extra()
                     span.set_metadata(n_admitted=report.n_admitted - n_admitted,
                                       n_shed=report.n_shed - n_shed)
                 # ---- paced: hold until this window's wall-clock start -
@@ -973,13 +956,7 @@ class StreamEngine:
                         "p2m.fold", window=window,
                         chunks=self.chunks_per_window,
                         slots=self.n_sub):
-                    if self.adapt is None:
-                        state = self.fns.fold(state, frames_dev, active,
-                                              *extra)
-                    else:
-                        state, self.adapt_state = self.fns.fold(
-                            state, self.adapt_state, frames_dev, active,
-                            *extra)
+                    state = self.fns.fold(state, frames_dev, active, *extra)
                 # one entry per replay chunk, each an equal share of the
                 # window's feed: the bin wait, the copy, the fold dispatch
                 report.fold_s.extend(
@@ -996,14 +973,8 @@ class StreamEngine:
                 with TraceAnnotation("p2m.readout", window=window,
                                      backbone=self.backbone_kind,
                                      coarse_lanes=int(coarse_mask.sum())):
-                    if self.adapt is None:
-                        state, out = self.fns.readout(
-                            state, active, jnp.asarray(coarse_mask), *extra)
-                    else:
-                        state, self.adapt_state, out = self.fns.readout(
-                            state, self.adapt_state, active,
-                            jnp.asarray(coarse_mask),
-                            jnp.asarray(self._labels), *extra)
+                    state, out = self.fns.readout(
+                        state, active, jnp.asarray(coarse_mask), *extra)
                 with TraceAnnotation("p2m.sync", window=window):
                     n_spikes = np.asarray(out["n_spikes"])  # window sync
                 t_done = time.perf_counter()
@@ -1059,8 +1030,6 @@ class StreamEngine:
                             n_layer1_spikes=lane.n_layer1_spikes,
                             logits=[float(v) for v in logits]))
                         slots.release(lane_i)
-                        if self.adapt is not None:
-                            self._labels[lane_i] = -1
                         if self.registry is not None:
                             self._unbind_entry(lane.entry_slot)
                     if log is not None:
@@ -1074,9 +1043,10 @@ class StreamEngine:
             # no daemon thread leaks holding an open stream iterator
             if pool is not None:
                 pool.close()
+            self.state = state
         report.wall_s = time.perf_counter() - t_start
         if self.adapt is not None:
-            lanes = lane_stats(jax.device_get(self.adapt_state))
+            lanes = lane_stats(jax.device_get(self.state["adapt"]))
 
             def _acc(rs: list[StreamResult]) -> float | None:
                 return (sum(r.correct for r in rs) / len(rs)
@@ -1120,7 +1090,7 @@ class StreamEngine:
         if base is None:
             raise ValueError(f"lane {lane} never served a stream — no "
                              f"base entry to delta against")
-        ast = jax.device_get(self.adapt_state)
+        ast = jax.device_get(self.state["adapt"])
         return {
             "lane": lane,
             "dw": np.asarray(ast["dw"][lane]),

@@ -115,7 +115,7 @@ def test_serving_fold_step_with_kernel(one_chip, monkeypatch, slots):
     monkeypatch.setattr(stream_fold, "resolve_interpret", lambda _: False)
     dep = deploy.fresh_deployment(p2m_dvs.CONFIG, seed=0)
     capacity = 16
-    fns = accumulator.make_stream_fns(dep, capacity=capacity, chunk_slots=1,
+    fns = accumulator.make_stream_fns(dep, capacity=capacity,
                                       use_kernel=True)
     state = jax.eval_shape(fns.init_state)
     state = jax.tree.map(lambda s: _sds(s.shape, one_chip, s.dtype), state)
@@ -135,8 +135,7 @@ def test_spikformer_readout_step(one_chip):
 
     dep = deploy.fresh_deployment(p2m_spikformer.CONFIG, seed=0)
     capacity = 8
-    fns = accumulator.make_stream_fns(dep, capacity=capacity,
-                                      chunk_slots=S)
+    fns = accumulator.make_stream_fns(dep, capacity=capacity)
     state = jax.eval_shape(fns.init_state)
     state = jax.tree.map(lambda s: _sds(s.shape, one_chip, s.dtype), state)
     mask = _sds((capacity,), one_chip, jnp.bool_)

@@ -144,7 +144,7 @@ def test_served_equals_the_offline_forward(setup, capacity):
 
 
 def test_registry_serving_equals_single_serving(setup):
-    """make_multi_stream_fns steps the backbone through the same seam:
+    """make_stream_fns(registry=True) steps the backbone through the same seam:
     one Spikformer entry in a registry serves what the deployment alone
     serves."""
     from repro.stream.registry import Registry

@@ -125,8 +125,8 @@ class TestKernelGuard:
     def test_make_adapt_fns_raises(self):
         _, dep = _dep()
         with pytest.raises(ValueError, match="use_kernel"):
-            make_adapt_fns(dep, capacity=2, chunk_slots=1,
-                           adapt=AdaptConfig(), use_kernel=True)
+            make_adapt_fns(dep, capacity=2, adapt=AdaptConfig(),
+                           use_kernel=True)
 
     def test_engine_raises(self):
         _, dep = _dep()
@@ -146,17 +146,27 @@ class TestKernelGuard:
 # ---------------------------------------------------------------------------
 
 class TestAdaptOffParity:
-    def test_zero_lr_bit_identical_to_frozen(self):
+    @pytest.mark.parametrize("served", ["deployment", "registry"])
+    def test_zero_lr_bit_identical_to_frozen(self, served):
         """lr_w = lr_theta = 0: updates fire but deltas stay exactly 0,
         so the relinearized per-lane forward must reproduce the frozen
-        engine's logits bit-for-bit."""
+        engine's logits bit-for-bit — serving one deployment, or a
+        two-entry registry whose lanes gather their base numerics from
+        the bundle."""
         src, dep = _dep()
-        frozen = StreamEngine(dep, capacity=4).serve(src, 6, seed=0)
+        variants = None
+        if served == "registry":
+            reg = Registry()
+            reg.register("a", dep)
+            reg.register("b", _dep(seed=1)[1])
+            dep, variants = reg, ["a", "b"] * 3
+        frozen = StreamEngine(dep, capacity=4).serve(src, 6, seed=0,
+                                                     variants=variants)
         eng = StreamEngine(dep, capacity=4,
                            adapt=AdaptConfig(lr_w=0.0, lr_theta=0.0))
-        adapted = eng.serve(src, 6, seed=0)
+        adapted = eng.serve(src, 6, seed=0, variants=variants)
         _assert_bitexact(frozen, adapted)
-        ast = jax.device_get(eng.adapt_state)
+        ast = jax.device_get(eng.state["adapt"])
         np.testing.assert_array_equal(np.asarray(ast["dw"]), 0.0)
         np.testing.assert_array_equal(np.asarray(ast["dtheta"]), 0.0)
 
@@ -169,7 +179,7 @@ class TestAdaptOffParity:
                            adapt=AdaptConfig(lr_w=0.5, lr_theta=1e-3))
         adapted = eng.serve(_MaskLabels(src, labeled=()), 4, seed=0)
         _assert_bitexact(frozen, adapted)
-        ast = jax.device_get(eng.adapt_state)
+        ast = jax.device_get(eng.state["adapt"])
         assert int(np.asarray(ast["n_updates"]).sum()) == 0
         np.testing.assert_array_equal(np.asarray(ast["dw"]), 0.0)
 
@@ -199,7 +209,7 @@ class TestLaneIsolation:
                            adapt=AdaptConfig(rule="surrogate", lr_w=0.5))
         rep = eng.serve(_MaskLabels(src, labeled=(0, 2)), 4, seed=0)
 
-        ast = jax.device_get(eng.adapt_state)
+        ast = jax.device_get(eng.state["adapt"])
         n_upd = np.asarray(ast["n_updates"])
         dw = np.asarray(ast["dw"])
         assert n_upd[0] > 0 and n_upd[2] > 0
@@ -221,7 +231,7 @@ class TestLaneIsolation:
         eng = StreamEngine(dep, capacity=4,
                            adapt=AdaptConfig(rule="reward", lr_w=0.1))
         eng.serve(_MaskLabels(src, labeled=(1,)), 4, seed=0)
-        ast = jax.device_get(eng.adapt_state)
+        ast = jax.device_get(eng.state["adapt"])
         n_upd = np.asarray(ast["n_updates"])
         assert n_upd[1] > 0
         for lane in (0, 2, 3):
@@ -451,7 +461,7 @@ _MESH_SCRIPT = textwrap.dedent("""
         eng = StreamEngine(dep, capacity=4, adapt=off,
                            executor=make_lane_executor(dev))
         assert_same(frozen, eng.serve(src, 6, seed=0), f"off_d{dev}")
-        ast = jax.device_get(eng.adapt_state)
+        ast = jax.device_get(eng.state["adapt"])
         assert float(np.abs(np.asarray(ast["dw"])).max()) == 0.0
 
     # 2) LEARNING on the mesh == learning on one device: per-lane
@@ -464,8 +474,8 @@ _MESH_SCRIPT = textwrap.dedent("""
                       executor=make_lane_executor(2))
     r2 = e2.serve(src, 6, seed=0)
     assert_same(r1, r2, "learn_d2")
-    a1 = jax.device_get(e1.adapt_state)
-    a2 = jax.device_get(e2.adapt_state)
+    a1 = jax.device_get(e1.state["adapt"])
+    a2 = jax.device_get(e2.state["adapt"])
     assert int(np.asarray(a1["n_updates"]).sum()) > 0
     np.testing.assert_array_equal(np.asarray(a1["n_updates"]),
                                   np.asarray(a2["n_updates"]))

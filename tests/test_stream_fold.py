@@ -191,10 +191,8 @@ class TestAccumulatorWiring:
         dep = _deployment(CircuitConfig.NULLIFIED, 250.0)
         n_sub = dep.model_cfg.p2m.n_sub
         capacity = 3
-        fns_scan = accumulator.make_stream_fns(dep, capacity=capacity,
-                                               chunk_slots=n_sub)
+        fns_scan = accumulator.make_stream_fns(dep, capacity=capacity)
         fns_kern = accumulator.make_stream_fns(dep, capacity=capacity,
-                                               chunk_slots=n_sub,
                                                use_kernel=True)
         key = jax.random.PRNGKey(7)
         frames = jax.random.poisson(key, 0.3,
@@ -230,7 +228,6 @@ class TestAccumulatorWiring:
         off = deploy_mod.offline_forward(dep, frames[None])
 
         fns = accumulator.make_stream_fns(dep, capacity=2,
-                                          chunk_slots=n_sub,
                                           use_kernel=True)
         state = fns.init_state()
         active = jnp.asarray([True, False])

@@ -883,25 +883,21 @@ def _window_engine(deps, mode: str, chunks_per_window: int):
 
 
 def _wrap_fold(engine, wrap) -> None:
-    """Replace the engine's jitted fold by ``wrap(fold, frames_at)``;
-    ``frames_at`` is the frames' position among the fold's arguments."""
+    """Replace the engine's jitted fold by ``wrap(fold)``; every mode's
+    fold is ``fold(state, frames, active, *extra)``."""
     import dataclasses
 
-    frames_at = 1 if engine.adapt is None else 2
-    engine.fns = dataclasses.replace(
-        engine.fns, fold=wrap(engine.fns.fold, frames_at))
+    engine.fns = dataclasses.replace(engine.fns,
+                                     fold=wrap(engine.fns.fold))
 
 
-def _per_chunk(fold, frames_at: int, chunk_slots: int):
+def _per_chunk(fold, chunk_slots: int):
     """The oracle: the same jitted fold driven chunk by chunk over the
     window batch's sub-slot ranges, one call per replay chunk."""
-    def fold_chunks(*args):
-        carry, frames = args[:frames_at], args[frames_at]
-        rest = args[frames_at + 1:]
+    def fold_chunks(state, frames, *rest):
         for lo in range(0, frames.shape[1], chunk_slots):
-            out = fold(*carry, frames[:, lo:lo + chunk_slots], *rest)
-            carry = out if frames_at == 2 else (out,)
-        return out
+            state = fold(state, frames[:, lo:lo + chunk_slots], *rest)
+        return state
     return fold_chunks
 
 
@@ -917,18 +913,17 @@ def test_one_fold_per_window_matches_per_chunk_folds(
     engine, variants = _window_engine(window_deps, mode, chunks_per_window)
     seen = []
 
-    def counted(fold, frames_at):
-        def call(*args):
-            seen.append(np.array(args[frames_at]))
-            return fold(*args)
+    def counted(fold):
+        def call(state, frames, *rest):
+            seen.append(np.array(frames))
+            return fold(state, frames, *rest)
         return call
 
     _wrap_fold(engine, counted)
     got = engine.serve(window_src, 3, seed=0, variants=variants)
 
     oracle, _ = _window_engine(window_deps, mode, chunks_per_window)
-    _wrap_fold(oracle, lambda fold, at: _per_chunk(fold, at,
-                                                   oracle.chunk_slots))
+    _wrap_fold(oracle, lambda fold: _per_chunk(fold, oracle.chunk_slots))
     ref = oracle.serve(window_src, 3, seed=0, variants=variants)
 
     windows = len(got.readout_s)
@@ -969,6 +964,24 @@ def test_one_fold_per_window_matches_per_chunk_folds(
         assert getattr(ref, k) == getattr(got, k), k
     if mode == "adapt":
         assert got.adaptation["n_updates"] == ref.adaptation["n_updates"] > 0
+
+
+@pytest.mark.parametrize("mode", ["plain", "registry"])
+def test_second_serve_matches_a_fresh_engine(window_src, window_deps, mode):
+    """The engine's device lane state stays resident across ``serve``
+    calls and admission resets each lane: a second serve on one frozen
+    engine, over the lane state the first left, gives a fresh engine's
+    predictions, spike counts and logits, bit for bit."""
+    engine, variants = _window_engine(window_deps, mode, 1)
+    engine.serve(window_src, 3, seed=1, variants=variants)
+    got = engine.serve(window_src, 3, seed=0, variants=variants)
+    fresh, _ = _window_engine(window_deps, mode, 1)
+    ref = fresh.serve(window_src, 3, seed=0, variants=variants)
+    _assert_reports_identical(ref, got)
+    key = lambda r: r.stream_id  # noqa: E731
+    for a, b in zip(sorted(ref.results, key=key),
+                    sorted(got.results, key=key)):
+        assert a.n_layer1_spikes == b.n_layer1_spikes, a.stream_id
 
 
 # ---------------------------------------------------------------------------
